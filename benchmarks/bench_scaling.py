@@ -1,60 +1,43 @@
 """Paper Fig. 14: near-linear QPS scaling with #DPUs (= devices).
 
-Spawns subprocesses with --xla_force_host_platform_device_count in {1,2,4,8}
-(one physical core here, so wall-QPS saturates; the *scheduled-load-per-
-device* column is the scaling signal, matching the paper's aggregated-
-bandwidth argument) and fits the regression the paper uses."""
+Runs in one process over retrieval meshes of the first 1, 2, 4 and 8
+devices (`make_retrieval_mesh(n)`, as many as the backend has), so a
+process that holds the chip never starts a child that needs it.  On the
+CPU fake-device mesh the devices share the host's cores, so wall-QPS
+saturates; the *scheduled-load-per-device* column is the scaling signal,
+matching the paper's aggregated-bandwidth argument."""
 
 from __future__ import annotations
 
-import json
-import os
-import subprocess
-import sys
+import time
 
-import numpy as np
+import jax
 
-from benchmarks.common import emit
-
-_CHILD = r"""
-import os, sys, json, time
-os.environ["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={sys.argv[1]}"
-import numpy as np, jax
-from benchmarks.common import small_system
-xs, stream, eng = small_system(n=15000, c=48)
-qs = stream.queries(64, seed=2)
-eng.search(qs, nprobe=8, k=10)  # warm
-t0 = time.perf_counter(); eng.search(qs, nprobe=8, k=10)
-wall = time.perf_counter() - t0
-sch, _, _ = eng.schedule_batch(qs, 8)
-print(json.dumps({
-    "ndev": int(sys.argv[1]),
-    "qps": len(qs) / wall,
-    "max_dev_load": float(sch.dev_load.max()),
-    "mean_dev_load": float(sch.dev_load.mean()),
-}))
-"""
+from benchmarks.common import emit, small_system
+from repro.launch.mesh import make_retrieval_mesh
 
 
 def run():
-    env = dict(os.environ)
-    env["PYTHONPATH"] = "src:."
     loads = []
     for ndev in (1, 2, 4, 8):
-        out = subprocess.run(
-            [sys.executable, "-c", _CHILD, str(ndev)],
-            capture_output=True, text=True, env=env, timeout=1200,
+        if ndev > len(jax.devices()):
+            break
+        _, stream, eng = small_system(
+            n=15000, c=48, mesh=make_retrieval_mesh(ndev)
         )
-        if out.returncode != 0:
-            emit(f"fig14_scaling_dev{ndev}", -1, "FAIL")
-            continue
-        rep = json.loads(out.stdout.strip().splitlines()[-1])
-        loads.append((ndev, rep["max_dev_load"]))
+        qs = stream.queries(64, seed=2)
+        eng.search(qs, nprobe=8, k=10)  # warm
+        t0 = time.perf_counter()
+        eng.search(qs, nprobe=8, k=10)
+        qps = len(qs) / (time.perf_counter() - t0)
+        sch, _, _ = eng.schedule_batch(qs, 8)
+        max_load = float(sch.dev_load.max())
+        loads.append((ndev, max_load))
         emit(
             f"fig14_scaling_dev{ndev}",
-            1e6 / rep["qps"],
-            f"qps={rep['qps']:.1f};max_dev_load={rep['max_dev_load']:.0f};"
-            f"mean_dev_load={rep['mean_dev_load']:.0f}",
+            1e6 / qps,
+            f"qps={qps:.1f};max_dev_load={max_load:.0f};"
+            f"mean_dev_load={float(sch.dev_load.mean()):.0f}",
         )
     if len(loads) >= 2:
         # per-device load should scale ~1/ndev (aggregated-bandwidth claim)

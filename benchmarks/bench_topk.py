@@ -48,8 +48,8 @@ def run():
         starts.append(cursor)
         cursor += -(-s // bn) * bn
     p = len(sizes)
-    codes_dev = jnp.asarray(
-        RNG.integers(0, 256, (cursor, m2)).astype(np.uint8)
+    codes_dev = jnp.asarray(  # column-major (W, cap), as the engine ships
+        RNG.integers(0, 256, (m2, cursor)).astype(np.uint8)
     )
     tables = jnp.asarray(
         RNG.normal(0, 1, (p, m2 * 256 + 1)).astype(np.float32)

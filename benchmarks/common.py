@@ -1,8 +1,9 @@
 """Shared benchmark utilities: timing, CSV emission, shared datasets.
 
-CPU timings here are *relative* (interpret-mode Pallas + host CPU); the
-absolute performance story lives in EXPERIMENTS.md §Roofline, derived from
-the compiled dry-run.  Each bench reproduces the SHAPE of a paper figure.
+Timings are wall-clock on the backend the harness runs on.  On the CPU
+(interpret-mode Pallas + fake devices) they are *relative* signals between
+code paths, not device metrics; only a run on a TPU measures the device.
+Each bench reproduces the SHAPE of a paper figure.
 """
 
 from __future__ import annotations
@@ -79,8 +80,11 @@ def scan_ideal_bytes(eng, plan) -> int:
     return rows * eng.shards.width * eng.shards.codes.dtype.itemsize
 
 
-def small_system(n=15000, c=48, m=8, dim=32, use_cooc=False, seed=0):
-    """Shared small MemANNS system for online-path benches."""
+def small_system(
+    n=15000, c=48, m=8, dim=32, use_cooc=False, seed=0, mesh=None
+):
+    """Shared small MemANNS system for online-path benches (`mesh`: the
+    device mesh to shard over; default every device)."""
     import jax as _jax
 
     from repro.data import SkewedVectorDataset, make_clustered_vectors
@@ -94,6 +98,6 @@ def small_system(n=15000, c=48, m=8, dim=32, use_cooc=False, seed=0):
         _jax.random.PRNGKey(0), xs, c, m,
         history_queries=stream.queries(200, seed=1),
         use_cooc=use_cooc, n_combos=32, block_n=256,
-        kmeans_iters=8, pq_iters=6,
+        kmeans_iters=8, pq_iters=6, mesh=mesh,
     )
     return xs, stream, eng

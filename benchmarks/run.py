@@ -2,9 +2,11 @@
 
 ``PYTHONPATH=src python -m benchmarks.run [--only fig13] [--json BENCH_5.json]``
 
-Prints ``name,us_per_call,derived`` CSV rows (plus a header).  CPU wall-times
-are relative signals; absolute TPU-v5e performance derives from the compiled
-dry-run (EXPERIMENTS.md §Roofline).
+Prints ``name,us_per_call,derived`` CSV rows (plus a header).  Times are
+wall-clock on whatever backend runs the harness, stamped on every row: only
+rows measured on a TPU are device numbers.  On the CPU (Pallas interpret
+mode, fake devices) they are relative signals between code paths, never
+device metrics.
 
 ``--json PATH`` additionally records every emitted row in a machine-readable
 file (per-sub-bench QPS / latency / rows-scanned / tiles-skipped and any
@@ -13,14 +15,14 @@ file so CI steps that run different ``--only`` slices accumulate one
 ``BENCH_<pr>.json`` artifact tracking the perf trajectory across PRs.
 
 Every row is stamped with the measurement context (``backend`` /
-``device_kind`` / ``autotune`` mode), and rows that report their ideal
-probed-code bytes (``ideal_bytes=...`` in the derived column) gain a
-``roofline_frac`` column -- (ideal_bytes / HBM bandwidth) / measured
+``device_kind`` / ``autotune`` mode).  Rows measured on a TPU that report
+their ideal probed-code bytes (``ideal_bytes=...`` in the derived column)
+gain a ``roofline_frac`` column -- (ideal_bytes / HBM bandwidth) / measured
 seconds, peaks resolved per device kind via
-`repro.launch.roofline_report.peaks_for` with the honest ``peaks_source``
-recorded next to it -- so "as fast as the hardware allows" is a number in
-the artifact, not a claim.  `repro.launch.env.setup_env` runs before jax
-initializes (XLA flags and platform defaults; CI's pinned env always wins).
+`repro.launch.roofline_report.peaks_for` with ``peaks_source`` recorded
+next to it.  Rows from any other backend carry no roofline column.
+`repro.launch.env.setup_env` runs before jax initializes (XLA flags,
+platform defaults and the compile cache; CI's pinned env always wins).
 """
 
 from __future__ import annotations
@@ -109,13 +111,16 @@ def write_json(
             # breakdown) attached via benchmarks.common.emit(stats=...)
             row["metrics"] = extra[0]
         # roofline fraction: ideal code-stream seconds / measured seconds
-        # (only for rows that report their ideal byte traffic)
+        # (only for TPU rows that report their ideal byte traffic)
         hbm_bw = meta.get("hbm_bw")
-        if hbm_bw and row.get("ideal_bytes") and us_per_call > 0:
+        if (
+            meta.get("backend") == "tpu" and hbm_bw
+            and row.get("ideal_bytes") and us_per_call > 0
+        ):
             row["roofline_frac"] = (
                 row["ideal_bytes"] / hbm_bw / (us_per_call * 1e-6)
             )
-            row["peaks_source"] = meta.get("peaks_source", "default")
+            row["peaks_source"] = meta["peaks_source"]
         doc["rows"][name] = row
     for mod_name, msg in (errors or {}).items():
         doc["rows"][mod_name] = {
@@ -159,16 +164,18 @@ def main() -> None:
     from repro.launch.roofline_report import peaks_for
 
     env = describe_env()
-    peak_flops, hbm_bw, peaks_source = peaks_for(env["device_kind"])
     meta = {
         "backend": env["backend"],
         "device_kind": env["device_kind"],
         "n_devices": env["n_devices"],
         "autotune": args.autotune,
-        "peak_flops": peak_flops,
-        "hbm_bw": hbm_bw,
-        "peaks_source": peaks_source,
     }
+    peaks_source = "none"
+    if env["backend"] == "tpu":  # roofline shares only for device rows
+        peak_flops, hbm_bw, peaks_source = peaks_for(env["device_kind"])
+        meta.update(
+            peak_flops=peak_flops, hbm_bw=hbm_bw, peaks_source=peaks_source
+        )
 
     print("name,us_per_call,derived")
     print(

@@ -227,7 +227,7 @@ def _time_scan(
 
     def fn():
         return ops.adc_topk_tiles(
-            tables, codes, tile_pair, tile_block, tile_row0, n_valid,
+            tables, codes.T, tile_pair, tile_block, tile_row0, n_valid,
             max(k, 1),
             block_n=block_n, path=engine.path, add_offsets=s.add_offsets,
             interpret=engine.interpret,

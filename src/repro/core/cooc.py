@@ -26,7 +26,6 @@ bit up to float addition reordering -- the optimization never changes recall
 from __future__ import annotations
 
 import dataclasses
-import itertools
 
 import jax
 import jax.numpy as jnp
@@ -118,15 +117,12 @@ def mine_combos(
     if top_pairs is None:
         top_pairs = 4 * n_combos
 
-    c32 = codes.astype(np.int64)
+    # positioned item ids col*256 + code; pair keys fit int32 for m <= 181
+    pid = np.arange(m, dtype=np.int32) * NCODES + codes.astype(np.int32)
+    key_t = np.int32 if (m * NCODES) ** 2 < 2**31 else np.int64
     # --- 1. count positioned pairs over all column pairs -------------------
-    keys = []
-    pair_cols = list(itertools.combinations(range(m), 2))
-    for c1, c2 in pair_cols:
-        pid1 = c1 * NCODES + c32[:, c1]
-        pid2 = c2 * NCODES + c32[:, c2]
-        keys.append(pid1 * (m * NCODES) + pid2)
-    keys = np.concatenate(keys)
+    c1s, c2s = np.triu_indices(m, 1)
+    keys = pid[:, c1s].astype(key_t) * (m * NCODES) + pid[:, c2s]
     uniq, counts = np.unique(keys, return_counts=True)
     order = np.argsort(-counts, kind="stable")[:top_pairs]
     uniq, counts = uniq[order], counts[order]
@@ -144,7 +140,6 @@ def mine_combos(
         c1, j1 = divmod(pid1, NCODES)
         c2, j2 = divmod(pid2, NCODES)
         rows = (codes[:, c1] == j1) & (codes[:, c2] == j2)
-        sub = codes[rows]
         if combo_len == 2:
             sig = ((c1, j1), (c2, j2))
             if sig not in seen:
@@ -153,16 +148,15 @@ def mine_combos(
                 out_codes.append((j1, j2))
                 out_sup.append(int(cnt))
             continue
-        # best third positioned item among remaining columns
-        best = (-1, -1, -1)  # (support, col, code)
-        for c3 in range(m):
-            if c3 in (c1, c2):
-                continue
-            bc = np.bincount(sub[:, c3], minlength=NCODES)
-            j3 = int(bc.argmax())
-            if bc[j3] > best[0]:
-                best = (int(bc[j3]), c3, j3)
-        sup3, c3, j3 = best
+        # best third positioned item among remaining columns: the most
+        # frequent code per column, the first column on ties
+        bc = np.bincount(pid[rows].ravel(), minlength=m * NCODES)
+        bc = bc.reshape(m, NCODES)
+        j3s = bc.argmax(axis=1)
+        sup = bc[np.arange(m), j3s]
+        sup[[c1, c2]] = -1
+        c3 = int(sup.argmax())
+        sup3, j3 = int(sup[c3]), int(j3s[c3])
         if sup3 < min_support:
             continue
         tri = sorted([(c1, j1), (c2, j2), (c3, j3)])
@@ -183,6 +177,11 @@ def mine_combos(
         codes=np.asarray(out_codes, np.int32)[order],
         support=np.asarray(out_sup, np.int64)[order],
     )
+
+
+# rows matched against every combo at once in `reencode` (bounds the
+# (rows, n_combos) match matrix)
+_REENCODE_ROWS = 1 << 15
 
 
 def reencode(
@@ -209,30 +208,38 @@ def reencode(
     assert table <= 65536, "direct addresses must fit uint16 (paper §4.3)"
     sentinel = table - 1
 
+    # column-major working copies: one column's rows are contiguous
+    codes_t = np.ascontiguousarray(codes.T)
     # base: direct address col*256 + code (original items, uint16 in paper)
-    addr = (np.arange(m)[None, :] * NCODES + codes.astype(np.int32)).astype(
-        np.int32
-    )
-    removed = np.zeros((n, m), bool)
+    addr_t = np.arange(m, dtype=np.int32)[:, None] * NCODES + codes_t
+    removed_t = np.zeros((m, n), bool)
     # columns consumed by an applied combo (anchor AND elided): a later combo
     # may not reuse any of them -- otherwise it would overwrite the anchor
     # address or elide it (hypothesis-found bug: overlapping anchors)
-    used = np.zeros((n, m), bool)
+    used_t = np.zeros((m, n), bool)
 
-    for s in range(n_combos):
-        ccols = combos.cols[s]
-        ccodes = combos.codes[s]
-        if len(set(ccols.tolist())) < len(ccols):
-            continue  # padding/dummy combo (duplicate columns): never matches
-        match = np.all(codes[:, ccols] == ccodes[None, :], axis=1)
-        free = ~used[:, ccols].any(axis=1)
-        rows = match & free
-        if not rows.any():
-            continue
-        # first column carries the combo address; the rest are elided
-        addr[rows, ccols[0]] = m * NCODES + s
-        removed[np.ix_(np.flatnonzero(rows), ccols[1:])] = True
-        used[np.ix_(np.flatnonzero(rows), ccols)] = True
+    cols, ccodes = combos.cols, combos.codes
+    # padding/dummy combos (duplicate columns) never match
+    distinct = np.asarray(
+        [len(set(c.tolist())) == len(c) for c in cols], bool
+    )
+    # each row's re-encoding depends on that row alone: match in row chunks
+    for r0 in range(0, n, _REENCODE_ROWS):
+        rs = slice(r0, min(n, r0 + _REENCODE_ROWS))
+        match = np.repeat(distinct[:, None], rs.stop - r0, axis=1)
+        for t in range(cols.shape[1]):
+            match &= codes_t[cols[:, t], rs] == ccodes[:, t, None]
+        for s in np.flatnonzero(match.any(axis=1)):  # support order
+            ccols = cols[s]
+            ok = match[s] & ~used_t[ccols, rs].any(axis=0)
+            if not ok.any():
+                continue
+            rows = np.flatnonzero(ok) + r0
+            # first column carries the combo address; the rest are elided
+            addr_t[ccols[0], rows] = m * NCODES + s
+            removed_t[ccols[1:, None], rows] = True
+            used_t[ccols[:, None], rows] = True
+    addr, removed = addr_t.T, removed_t.T
 
     keep = ~removed
     lengths = keep.sum(axis=1).astype(np.int32)

@@ -303,11 +303,17 @@ def delta_topk_block(
 
     dists = jax.vmap(per_q)(luts_flat, col)             # (Q, cap)
     found = found & (dists <= bound[:, None])
-    vals, idx = masked_topk_smallest(dists, found, k)
+    # top_k needs k <= cap: a buffer smaller than k pads with (+inf, -1)
+    kk = min(k, dists.shape[1])
+    vals, idx = masked_topk_smallest(dists, found, kk)
     good = vals < jnp.finfo(vals.dtype).max
     out_i = jnp.where(good, vec_ids[idx], -1)
     out_d = jnp.where(good, vals, jnp.inf)
-    return out_d, out_i
+    pad = ((0, 0), (0, k - kk))
+    return (
+        jnp.pad(out_d, pad, constant_values=jnp.inf),
+        jnp.pad(out_i, pad, constant_values=-1),
+    )
 
 
 def delta_topk(

@@ -328,13 +328,19 @@ def search(
         best_i = np.full(k, -1, np.int64)
         for pi in range(nprobe):
             seg = segs[pi]
-            if len(seg) == 0:
+            n_seg = len(seg)
+            if n_seg == 0:
                 continue
-            kk = min(k, len(seg))
+            kk = min(k, n_seg)
+            # pad to a pow2 width >= k: top_k needs k rows, and a few
+            # widths keep the jitted scan from compiling per cluster size
+            width = max(k, 1 << (n_seg - 1).bit_length())
+            padded = np.zeros((width, seg.shape[1]), seg.dtype)
+            padded[:n_seg] = seg
             d, li = scan_fn(
                 jnp.asarray(luts[pi]),
-                jnp.asarray(seg),
-                jnp.ones(len(seg), bool),
+                jnp.asarray(padded),
+                jnp.asarray(np.arange(width) < n_seg),
             )
             d = np.asarray(d)[:kk]
             gi = index.cluster_ids(probe[pi])[np.asarray(li)[:kk]]

@@ -5,9 +5,9 @@ L2 distance between the m-th subsegment of (q - c) and codeword j of
 sub-codebook B_m.  ADC then scores a point with codes e as
     L2(q, x) ~= sum_m LUT[m, e_m].
 
-On UPMEM the LUT lives in WRAM (8 KB for M=16 uint16 entries); on TPU it is
-pinned in VMEM by the Pallas kernels (kernels/lut_build.py fuses this whole
-module with the scan; this file is the jnp reference / host path).
+On UPMEM the LUT lives in WRAM (8 KB for M=16 uint16 entries); on TPU the
+scan kernels pin it in VMEM.  This module is the one LUT build of every path:
+the host reference and the device step (`kernels.ops.build_luts`) alike.
 """
 
 from __future__ import annotations

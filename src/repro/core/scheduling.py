@@ -476,6 +476,46 @@ def count_tiles(
     return ((nv + block_n - 1) // block_n).sum(axis=1)
 
 
+def query_pair_index(
+    pair_q: np.ndarray,
+    pair_valid: np.ndarray,
+    n_queries: int,
+    width: int,
+) -> np.ndarray:
+    """(ndev, Q, width) int32: each query's pair slots on each device.
+
+    Row (d, q) lists, in ascending slot order, the slots of device d's pair
+    list that belong to query q, padded with P (== pairs_per_dev, the
+    device step's all-(+inf, -1) dummy row).  The device step gathers a
+    query's per-pair top-k lists through it, so the per-query merge reads
+    (Q, width * k) candidates instead of every pair of the device.
+    `width` must cover the most pairs a query holds on one device (nprobe
+    does).
+
+    Args:
+      pair_q: (ndev, P) int32 query of each pair slot.
+      pair_valid: (ndev, P) bool, False on densify padding.
+    """
+    ndev, p = pair_q.shape
+    out = np.full((ndev, n_queries, width), p, np.int32)
+    d, s = np.nonzero(pair_valid)          # row-major: slots ascending
+    q = pair_q[d, s]
+    order = np.lexsort((s, q, d))          # group by (device, query)
+    d, s, q = d[order], s[order], q[order]
+    group = d.astype(np.int64) * n_queries + q
+    first = np.r_[0, np.flatnonzero(group[1:] != group[:-1]) + 1]
+    rank = np.arange(group.size) - np.repeat(
+        first, np.diff(np.r_[first, group.size])
+    )
+    if rank.size and int(rank.max()) >= width:
+        raise ValueError(
+            f"a query holds {int(rank.max()) + 1} pairs on one device; "
+            f"query index width is {width}"
+        )
+    out[d, q, rank] = s
+    return out
+
+
 def emit_tiles(
     pair_slot: np.ndarray,
     pair_valid: np.ndarray,
@@ -491,8 +531,8 @@ def emit_tiles(
     Each valid (query, cluster) pair expands to ceil(slot_size / block_n)
     tiles; the per-device tile lists are padded to `tiles_per_dev` with
     dummy tiles whose pair id is P (== pairs_per_dev) -- the tiles kernel
-    appends a zero table row and a zero n_valid entry at index P, so dummy
-    tiles always prune away.  Within a pair, tiles appear in ascending row
+    gives pair P no valid rows and an infinite lower bound, so dummy tiles
+    always prune away.  Within a pair, tiles appear in ascending row
     order, so the kernel's running merge visits exactly the same tile
     sequence as the padded-window path (bit-identical results).
 

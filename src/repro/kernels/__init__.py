@@ -3,7 +3,7 @@
   adc_scan.py  -- ADC distance scan (gather + one-hot-GEMM paths)
   adc_topk.py  -- fused scan + running top-k with §4.4 early pruning
                   (shared-codes and per-pair-window variants)
-  lut_build.py -- LUT construction + fused [LUT | combo-sums | 0] tables
+  lut_build.py -- fused [LUT | combo-sums | 0] tables
   ops.py       -- public jit'd wrappers (padding, dtypes, dispatch)
   ref.py       -- pure-jnp oracles, one per kernel
 """

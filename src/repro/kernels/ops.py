@@ -1,15 +1,18 @@
 """Public jit'd wrappers for the Pallas kernels: padding, dtype widening,
 block-size selection, backend dispatch (interpret=True off-TPU).
 
+Tables are handed to the kernels at any width: `adc_scan.as_chunks` pads
+them to a LANE multiple and views them as (A / 128, 128) chunk rows.
+
 API (all return the same values as the matching ref.py oracle):
   adc_scan(lut, codes)                plain ADC distances
   adc_scan_flat(ext_lut, addrs)       direct-address ADC distances
   adc_topk(luts, codes, k)            fused scan + top-k (multi-query)
   adc_topk_flat(ext_luts, addrs, k)   ... over co-occ encoded codes
   adc_topk_pairs(tables, addrs, ...)  per-pair materialized windows
-  adc_topk_windows(tables, codes, .)  per-pair padded windows, shared codes
-  adc_topk_tiles(tables, codes, ...)  flat tile work queue, shared codes
-  build_luts(codebook, qmc)           stage-(b) LUT construction
+  adc_topk_windows(tables, codes_t,.) per-pair padded windows, shared codes
+  adc_topk_tiles(tables, codes_t, .)  flat tile work queue, shared codes
+  build_luts(codebook, qmc)           stage-(b) LUT construction (jnp)
   build_ext_luts(luts, cols, codes)   fused [LUT | combo sums | 0] tables
   rerank_dists(queries, cand)         exact f32 re-rank distances (cascade)
 """
@@ -21,31 +24,25 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.core import lut as _core_lut
 from repro.kernels import adc_scan as _scan
 from repro.kernels import adc_topk as _topk
 from repro.kernels import lut_build as _lut
 from repro.kernels import rerank as _rerank
 
 NCODES = 256
-LANE = 128  # TPU lane width: pad tables/blocks to multiples of this
+LANE = _scan.LANE  # TPU lane width: pad tables/blocks to multiples of this
 
 
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
+def interpret_mode(interpret: bool | None = None) -> bool:
+    """Pallas interpret mode: as given, else on every backend but TPU."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return interpret
 
 
 def _round_up(x: int, mult: int) -> int:
     return (x + mult - 1) // mult * mult
-
-
-def _pad_table(table: jax.Array) -> jax.Array:
-    """Pad flat table width to a LANE multiple (one-hot GEMM alignment)."""
-    t = table.shape[-1]
-    pad = _round_up(t, LANE) - t
-    if pad == 0:
-        return table
-    widths = [(0, 0)] * (table.ndim - 1) + [(0, pad)]
-    return jnp.pad(table, widths)
 
 
 def _codes_to_addrs(codes: jax.Array) -> jax.Array:
@@ -75,10 +72,9 @@ def adc_scan(
     interpret: bool | None = None,
 ) -> jax.Array:
     """(M, 256) x (N, M) -> (N,) ADC distances via the Pallas kernel."""
-    if interpret is None:
-        interpret = _interpret_default()
+    interpret = interpret_mode(interpret)
     n = codes.shape[0]
-    table = _pad_table(lut.reshape(-1))
+    table = lut.reshape(-1)
     addrs = _pad_rows(_codes_to_addrs(codes), block_n, fill=0)
     out = _scan.adc_scan_kernel(
         table, addrs, block_n=block_n, path=path, interpret=interpret
@@ -98,15 +94,13 @@ def adc_scan_flat(
     interpret: bool | None = None,
 ) -> jax.Array:
     """(A,) x (N, W) direct-address scan -> (N,)."""
-    if interpret is None:
-        interpret = _interpret_default()
+    interpret = interpret_mode(interpret)
     n = addrs.shape[0]
-    table = _pad_table(ext_lut)
     # pad rows with the zero-sentinel address (A-1 of the unpadded table)
     sentinel = ext_lut.shape[-1] - 1
     addrs_p = _pad_rows(addrs.astype(jnp.int32), block_n, fill=sentinel)
     out = _scan.adc_scan_kernel(
-        table, addrs_p, block_n=block_n, path=path, interpret=interpret
+        ext_lut, addrs_p, block_n=block_n, path=path, interpret=interpret
     )
     return out[:n]
 
@@ -128,11 +122,10 @@ def adc_topk(
 
     `bound` is an optional (Q,) f32 per-query warm-start bound (a STRICT
     upper bound on the final k-th distance; see adc_topk.py)."""
-    if interpret is None:
-        interpret = _interpret_default()
+    interpret = interpret_mode(interpret)
     q = luts.shape[0]
     n = codes.shape[0]
-    tables = _pad_table(luts.reshape(q, -1))
+    tables = luts.reshape(q, -1)
     addrs = _pad_rows(_codes_to_addrs(codes), block_n, fill=0)
     n_valid = jnp.asarray([n], jnp.int32)
     return _topk.adc_topk_kernel(
@@ -161,15 +154,13 @@ def adc_topk_flat(
     bound: jax.Array | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """(Q, A) x (N, W) direct-address fused scan + top-k."""
-    if interpret is None:
-        interpret = _interpret_default()
+    interpret = interpret_mode(interpret)
     n = addrs.shape[0]
-    tables = _pad_table(ext_luts)
     sentinel = ext_luts.shape[-1] - 1
     addrs_p = _pad_rows(addrs.astype(jnp.int32), block_n, fill=sentinel)
     n_valid = jnp.asarray([n], jnp.int32)
     return _topk.adc_topk_kernel(
-        tables,
+        ext_luts,
         addrs_p,
         n_valid,
         k=k,
@@ -196,11 +187,9 @@ def adc_topk_pairs(
     """Per-pair fused scan+top-k: tables (P, A), addrs (P, L, W) int32
     (already flat/direct addresses), n_valid (P,).  L must be a block_n
     multiple (the retrieval layout aligns cluster slots)."""
-    if interpret is None:
-        interpret = _interpret_default()
-    tables_p = _pad_table(tables)
+    interpret = interpret_mode(interpret)
     return _topk.adc_topk_pairs_kernel(
-        tables_p,
+        tables,
         addrs.astype(jnp.int32),
         n_valid.astype(jnp.int32),
         k=k,
@@ -219,7 +208,7 @@ def adc_topk_pairs(
 )
 def adc_topk_windows(
     tables: jax.Array,
-    codes: jax.Array,
+    codes_t: jax.Array,
     starts: jax.Array,
     n_valid: jax.Array,
     k: int,
@@ -237,8 +226,9 @@ def adc_topk_windows(
 ):
     """Per-pair window scan over a shared device-resident code array.
 
-    tables (P, A); codes (cap, W) flat addresses (uint8 raw codes when
-    add_offsets -- widened in VMEM, so HBM sees the compact dtype); starts
+    tables (P, A); codes_t (W, cap) column-major flat addresses (uint8 raw
+    codes when add_offsets -- widened in VMEM, so HBM sees the compact
+    dtype); starts
     (P,) block_n-aligned row starts; n_valid (P,).  The production path:
     windows are indexed via scalar prefetch, never materialized.
 
@@ -247,13 +237,11 @@ def adc_topk_windows(
     With `with_stats=True` additionally returns the (P, 2) int32
     [tiles skipped, rows avoided] counters.
     """
-    if interpret is None:
-        interpret = _interpret_default()
-    tables_p = _pad_table(tables)
+    interpret = interpret_mode(interpret)
     start_blocks = starts.astype(jnp.int32) // block_n
     vals, idx, stats = _topk.adc_topk_windows_kernel(
-        tables_p,
-        codes,
+        tables,
+        codes_t,
         start_blocks,
         n_valid.astype(jnp.int32),
         k=k,
@@ -281,7 +269,7 @@ def adc_topk_windows(
 )
 def adc_topk_tiles(
     tables: jax.Array,
-    codes: jax.Array,
+    codes_t: jax.Array,
     tile_pair: jax.Array,
     tile_block: jax.Array,
     tile_row0: jax.Array,
@@ -300,23 +288,21 @@ def adc_topk_tiles(
 ):
     """Flat work-queue scan over a shared device-resident code array.
 
-    tables (P, A); codes (cap, W) (raw uint8 when add_offsets); tile_pair /
-    tile_block / tile_row0 (T,) int32 work items from `emit_tiles` (pair id
-    P marks dummy padding tiles); n_valid (P,).  One grid step per REAL code
-    tile -- device wall-clock is sum(actual probed rows), not
-    P * max-cluster window.
+    tables (P, A); codes_t (W, cap) column-major (raw uint8 when
+    add_offsets); tile_pair / tile_block / tile_row0 (T,) int32 work items
+    from `emit_tiles` (pair id P marks dummy padding tiles); n_valid (P,).
+    One grid step per REAL code tile -- device wall-clock is sum(actual
+    probed rows), not P * max-cluster window.
 
     `pair_q`/`pair_lb`/`bound` drive the early-pruning-v2 whole-tile skip
     (see adc_topk.py); the defaults reproduce the unpruned scan exactly.
     With `with_stats=True` additionally returns the (P, 2) int32
     [tiles skipped, rows avoided] counters.
     """
-    if interpret is None:
-        interpret = _interpret_default()
-    tables_p = _pad_table(tables)
+    interpret = interpret_mode(interpret)
     vals, idx, stats = _topk.adc_topk_tiles_kernel(
-        tables_p,
-        codes,
+        tables,
+        codes_t,
         tile_pair.astype(jnp.int32),
         tile_block.astype(jnp.int32),
         tile_row0.astype(jnp.int32),
@@ -357,8 +343,7 @@ def rerank_dists(
     every value, see rerank_dists_kernel).  Storage dtype may be f32 or
     bf16; sums are always f32.
     """
-    if interpret is None:
-        interpret = _interpret_default()
+    interpret = interpret_mode(interpret)
     bk = block_k or LANE
     k = cand.shape[1]
     kpad = _round_up(k, bk) - k
@@ -370,14 +355,15 @@ def rerank_dists(
     return out[:, :k]
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def build_luts(
-    codebook: jax.Array, qmc: jax.Array, *, interpret: bool | None = None
-) -> jax.Array:
-    """(M, 256, dsub) x (Q, M, dsub) -> (Q, M, 256)."""
-    if interpret is None:
-        interpret = _interpret_default()
-    return _lut.lut_build_kernel(codebook, qmc, interpret=interpret)
+@jax.jit
+def build_luts(codebook: jax.Array, qmc: jax.Array) -> jax.Array:
+    """(M, 256, dsub) x (Q, M, dsub) -> (Q, M, 256).
+
+    A plain fused XLA reduction on every backend (`core.lut.build_luts`):
+    the LUT build is elementwise work with a short reduction, which XLA
+    already streams at HBM rate."""
+    q = qmc.shape[0]
+    return _core_lut.build_luts(codebook, qmc.reshape(q, -1))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -390,17 +376,17 @@ def build_ext_luts(
 ) -> jax.Array:
     """Fused extended tables: (Q, M, 256) + (m, L) combos -> (Q, A).
 
-    A = M*256 + n_combos + 1 exactly (the sentinel is the last slot); any
-    LANE padding for the scan kernel happens inside adc_*_flat.
+    A = M*256 + n_combos + 1 exactly (the sentinel is the last slot); the
+    scan kernels pad tables to a LANE multiple themselves.
     """
-    if interpret is None:
-        interpret = _interpret_default()
+    interpret = interpret_mode(interpret)
     q, m, _ = luts.shape
     n_combos = combo_cols.shape[0]
     caddr = combo_cols.astype(jnp.int32) * NCODES + combo_codes.astype(
         jnp.int32
     )
-    t_pad = m * NCODES + n_combos + 1
-    return _lut.ext_lut_kernel(
-        luts, caddr, t_pad=t_pad, interpret=interpret
+    a = m * NCODES + n_combos + 1
+    out = _lut.ext_lut_kernel(
+        luts, caddr, t_pad=_round_up(a, LANE), interpret=interpret
     )
+    return out[:, :a]

@@ -12,9 +12,9 @@ Layout notes:
   * candidates reach the kernel already gathered (Q, K, D) -- the gather by
     candidate id happens in the shard_map step, where each device owns the
     rows of its home clusters (see retrieval.layout.RawStore);
-  * one (1, K) output row per grid step.  Full-array output blocks with a
-    constant index map crash XLA's sharding propagation under shard_map on
-    CPU (same pitfall as adc_topk.py), so the output is blocked per query;
+  * one (1, block_k) lane-dense output block per grid step: queries and
+    outputs carry a singleton middle axis so every block's last two dims
+    are either full or (8, 128)-aligned, as Mosaic requires;
   * distances are accumulated in f32 regardless of the storage dtype: a
     bf16 raw shard still yields f32 sums over bf16-rounded coordinates,
     which keeps the selection contract deterministic (see ops.rerank_dists).
@@ -38,9 +38,10 @@ from jax.experimental import pallas as pl
 
 def _rerank_dists_block(q_ref, cand_ref, out_ref):
     q = q_ref[...].astype(jnp.float32)          # (1, D)
-    cand = cand_ref[0].astype(jnp.float32)      # (Kb, D)
+    cand = cand_ref[...].astype(jnp.float32)    # (Kb, D)
     diff = cand - q                             # broadcast over Kb candidates
-    out_ref[...] = jnp.sum(diff * diff, axis=-1)[None]
+    # sum over D down the sublanes of the transpose: lane-dense (1, Kb)
+    out_ref[...] = jnp.sum((diff * diff).T, axis=0, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("block_k", "interpret"))
@@ -71,14 +72,15 @@ def rerank_dists_kernel(
         raise ValueError(
             f"rerank_dists_kernel: K={k} not a multiple of block_k={bk}"
         )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _rerank_dists_block,
         grid=(q, k // bk),
         in_specs=[
-            pl.BlockSpec((1, d), lambda qi, ki: (qi, 0)),
-            pl.BlockSpec((1, bk, d), lambda qi, ki: (qi, ki, 0)),
+            pl.BlockSpec((None, 1, d), lambda qi, ki: (qi, 0, 0)),
+            pl.BlockSpec((None, bk, d), lambda qi, ki: (qi, ki, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bk), lambda qi, ki: (qi, ki)),
-        out_shape=jax.ShapeDtypeStruct((q, k), jnp.float32),
+        out_specs=pl.BlockSpec((None, 1, bk), lambda qi, ki: (qi, 0, ki)),
+        out_shape=jax.ShapeDtypeStruct((q, 1, k), jnp.float32),
         interpret=interpret,
-    )(queries, cand)
+    )(queries.reshape(q, 1, d), cand)
+    return out.reshape(q, k)

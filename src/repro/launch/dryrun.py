@@ -1,7 +1,9 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# The two lines above MUST run before any jax import: jax locks the device
-# count at first init, and the multi-pod dry-run needs 512 host devices.
+os.environ["JAX_PLATFORMS"] = "cpu"
+# The lines above MUST run before any jax import: jax locks the device
+# count at first init, and the multi-pod dry-run needs 512 host devices --
+# on the CPU, so a dry-run never takes (or waits for) an accelerator.
 
 import argparse
 import dataclasses
@@ -38,9 +40,9 @@ from repro.training.trainer import make_train_step
 # the analytic cost model is pinned to the task-spec chip so dryrun numbers
 # stay comparable across machines; measured reporting resolves real peaks
 # per device kind via repro.launch.roofline_report.peaks_for
-from repro.launch.roofline_report import DEFAULT_PEAKS
+from repro.launch.roofline_report import PEAKS
 
-PEAK_FLOPS, HBM_BW = DEFAULT_PEAKS  # bf16 FLOP/s, HBM bytes/s / chip
+PEAK_FLOPS, HBM_BW = PEAKS["TPU v5e"]  # bf16 FLOP/s, HBM bytes/s / chip
 ICI_BW = 50e9                # bytes/s / link / chip
 
 _COLLECTIVE_RE = re.compile(

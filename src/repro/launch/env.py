@@ -6,7 +6,7 @@ initialization, so they only take effect if set BEFORE the first
 at the very top of `main()` (all their jax imports are deferred into the
 function body for exactly this reason) and only then build the mesh.
 
-Two rules keep this safe everywhere the repo runs:
+Three rules keep this safe everywhere the repo runs:
 
   * never clobber: every variable is set with `setdefault`, so CI's
     pinned `JAX_PLATFORMS=cpu` / `--xla_force_host_platform_device_count=8`
@@ -15,7 +15,13 @@ Two rules keep this safe everywhere the repo runs:
     when the caller asked for a specific one — the default lets jax pick
     the best available backend, and `describe_env()` reports what actually
     got initialized (backend + device kind), which the benchmark harness
-    stamps onto every emitted row.
+    stamps onto every emitted row.  A caller that must run on an
+    accelerator passes `platform` (e.g. "tpu"): JAX then fails at start-up
+    instead of carrying on on the CPU;
+  * one compile cache: JAX's persistent compilation cache lives where
+    `JAX_COMPILATION_CACHE_DIR` says when it is set, else at the fixed
+    path `<checkout>/.jax_cache` -- never a temp, pid- or time-based path,
+    since the path is part of the cache's key.
 
 The per-platform defaults follow the tuning guides (see SNIPPETS.md 1 & 3):
 GPU gets the latency-hiding scheduler + async collectives and a capped
@@ -28,6 +34,11 @@ defaults are already the tuned path.
 from __future__ import annotations
 
 import os
+from pathlib import Path
+
+# persistent compile cache used when JAX_COMPILATION_CACHE_DIR is unset:
+# a fixed path inside the checkout (listed in .gitignore)
+DEFAULT_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
 
 # fake-device count used when the caller pinned nothing: matches the CI
 # mesh so locally-run benches hit the same shard shapes CI publishes
@@ -79,6 +90,7 @@ def setup_env(
         # device arrays share the box without the allocator starving either
         setdefault("XLA_PYTHON_CLIENT_MEM_FRACTION", "0.85")
     setdefault("TF_CPP_MIN_LOG_LEVEL", "2")  # silence C++ backend chatter
+    setdefault("JAX_COMPILATION_CACHE_DIR", str(DEFAULT_CACHE_DIR))
     return applied
 
 

@@ -1,4 +1,4 @@
-"""Aggregate results/dryrun/*.json into the EXPERIMENTS.md roofline tables.
+"""Aggregate results/dryrun/*.json into markdown roofline tables.
 
 `PYTHONPATH=src python -m repro.launch.roofline_report --in results/dryrun`
 
@@ -9,13 +9,12 @@ The "what moves it" column is derived from which term dominates and the
 cell's useful-work ratio.
 
 The peaks are per-backend, not constants: `peaks_for` resolves
-(peak FLOP/s, HBM bytes/s) from the detected `device_kind` via `PEAKS`,
-falling back to the v5e-class default, and every report records a
-`peaks_source` ("table:<kind>" | "default" | "override") so a fraction
-computed against a guessed peak is never mistaken for a measured one.
-`--peak-flops` / `--hbm-bw` override both (e.g. for hardware not in the
-table); `benchmarks/run.py` uses the same resolver to stamp
-roofline-fraction columns onto bench rows that report ideal bytes.
+(peak FLOP/s, HBM bytes/s) from the detected `device_kind` via `PEAKS`
+and raises for a kind the table does not hold (a CPU among them), and
+every report records a `peaks_source` ("table:<kind>" | "override").
+`--peak-flops` / `--hbm-bw` together override both (e.g. for hardware not
+in the table); `benchmarks/run.py` uses the same resolver to stamp
+roofline-fraction columns onto bench rows measured on a TPU.
 """
 
 from __future__ import annotations
@@ -25,8 +24,9 @@ import glob
 import json
 import os
 
-# datasheet peaks keyed by a substring of jax's device_kind; dense-f32/bf16
-# peak FLOP/s and HBM bandwidth in bytes/s
+# datasheet peaks keyed by a substring of jax's device_kind; dense bf16
+# peak FLOP/s and HBM bandwidth in bytes/s (TPU v5e: Google Cloud
+# documentation, "TPU v5e")
 PEAKS: dict[str, tuple[float, float]] = {
     "TPU v4": (275e12, 1.2e12),
     "TPU v5 lite": (197e12, 819e9),
@@ -37,9 +37,6 @@ PEAKS: dict[str, tuple[float, float]] = {
     "A100": (312e12, 2.0e12),
     "H100": (989e12, 3.35e12),
 }
-# historical default (v5e-class) -- keeps old reports comparable when the
-# device kind is unknown (e.g. the CPU fake-device mesh)
-DEFAULT_PEAKS = (197e12, 819e9)
 
 
 def peaks_for(
@@ -49,25 +46,29 @@ def peaks_for(
 ) -> tuple[float, float, str]:
     """(peak FLOP/s, HBM bytes/s, source) for a device kind + overrides.
 
-    Explicit overrides win and mark the source "override"; otherwise the
+    Both overrides given win and mark the source "override"; otherwise the
     longest-matching `PEAKS` key contained in `device_kind` supplies the
-    pair ("table:<key>"), else `DEFAULT_PEAKS` ("default").
+    pair ("table:<key>", a single override replacing its half).  A device
+    kind the table does not hold raises: a roofline share against a
+    guessed peak is not a measurement.
     """
-    flops, bw = DEFAULT_PEAKS
-    source = "default"
-    if device_kind:
-        best = ""
-        for key in PEAKS:
-            if key.lower() in device_kind.lower() and len(key) > len(best):
-                best = key
-        if best:
-            flops, bw = PEAKS[best]
-            source = f"table:{best}"
+    if peak_flops is not None and hbm_bw is not None:
+        return peak_flops, hbm_bw, "override"
+    best = ""
+    for key in PEAKS:
+        if key.lower() in (device_kind or "").lower() and len(key) > len(best):
+            best = key
+    if not best:
+        raise ValueError(
+            f"no peaks for device kind {device_kind!r}; known: "
+            f"{sorted(PEAKS)} (or pass both --peak-flops and --hbm-bw)"
+        )
+    flops, bw = PEAKS[best]
     if peak_flops is not None or hbm_bw is not None:
         flops = peak_flops if peak_flops is not None else flops
         bw = hbm_bw if hbm_bw is not None else bw
-        source = "override"
-    return flops, bw, source
+        return flops, bw, "override"
+    return flops, bw, f"table:{best}"
 
 
 def advice(cell: dict) -> str:
@@ -84,9 +85,7 @@ def advice(cell: dict) -> str:
     return "MXU-align tile shapes; raise arithmetic intensity per HBM byte"
 
 
-def fraction(
-    cell: dict, peaks: tuple[float, float] = DEFAULT_PEAKS
-) -> float | None:
+def fraction(cell: dict, peaks: tuple[float, float]) -> float | None:
     peak_flops, hbm_bw = peaks
     b = cell.get("bound_s")
     if not b:
@@ -120,9 +119,7 @@ def fmt(x, nd=3):
     return str(x)
 
 
-def markdown_table(
-    cells: list[dict], peaks: tuple[float, float] = DEFAULT_PEAKS
-) -> str:
+def markdown_table(cells: list[dict], peaks: tuple[float, float]) -> str:
     hdr = (
         "| arch | shape | mesh | compute_s | memory_s | collective_s | "
         "dominant | model GF/chip | useful ratio | roofline frac | next move |\n"
@@ -162,9 +159,7 @@ def markdown_table(
     return hdr + "\n".join(rows) + "\n"
 
 
-def pick_hillclimb(
-    cells: list[dict], peaks: tuple[float, float] = DEFAULT_PEAKS
-) -> dict:
+def pick_hillclimb(cells: list[dict], peaks: tuple[float, float]) -> dict:
     ok = [c for c in cells if c.get("status") == "ok" and c["mesh"].startswith("pod")]
     with_fr = [(fraction(c, peaks), c) for c in ok]
     with_fr = [(f, c) for f, c in with_fr if f]
@@ -206,12 +201,9 @@ def main():
     args = ap.parse_args()
     kind = args.device_kind
     if kind is None and (args.peak_flops is None or args.hbm_bw is None):
-        try:  # aggregation also runs where jax can't initialize -- degrade
-            import jax
+        import jax
 
-            kind = jax.devices()[0].device_kind
-        except Exception:
-            kind = None
+        kind = jax.devices()[0].device_kind
     flops, bw, source = peaks_for(kind, args.peak_flops, args.hbm_bw)
     peaks = (flops, bw)
     cells = load(args.dirname)

@@ -29,6 +29,7 @@ from repro.core.scheduling import (
     count_tiles,
     densify_schedule,
     emit_tiles,
+    query_pair_index,
     residual_bounds,
     schedule_queries,
     subspace_code_norms,
@@ -80,6 +81,9 @@ class SearchPlan:
     schedule: ArraySchedule | None  # None for synthetic warmup plans
     n_queries: int
     pairs_per_dev: int
+    # (ndev, Q, S) each query's pair slots per device, padded with P
+    # (`query_pair_index`); S is the plan's query width
+    query_pairs: np.ndarray
     # tile-list work queue (scan="tiles" only; None on the windows path)
     tile_pair: np.ndarray | None = None   # (ndev, T) int32, P marks dummies
     tile_block: np.ndarray | None = None  # (ndev, T) int32 code-block index
@@ -215,6 +219,7 @@ class MemANNSEngine:
         min_length_reduction: float = 0.0,
         kmeans_iters: int = 15,
         pq_iters: int = 10,
+        train_subsample: int | None = None,
         path: str = "gather",
         scan: str = "tiles",
         prune: bool = True,
@@ -249,6 +254,8 @@ class MemANNSEngine:
         rotated on entry, and the raw shard (and therefore the exact
         re-rank) stays in the original space — squared L2 is rotation
         invariant, so the cascade contract is unchanged.
+        `train_subsample` caps the rows k-means and PQ train on (every row
+        is still assigned and encoded; see `core.index.build_index`).
 
         All knobs compose: `use_cooc=True` with `mutable=True` buffers
         inserts plain-coded in the delta (same jitted assign/encode path)
@@ -265,7 +272,8 @@ class MemANNSEngine:
         ndev = math.prod(mesh.devices.shape)
         index = build_index(
             key, xs, n_clusters, m, kmeans_iters=kmeans_iters,
-            pq_iters=pq_iters, opq_iters=opq_iters,
+            pq_iters=pq_iters, train_subsample=train_subsample,
+            opq_iters=opq_iters,
         )
         # f_i from the historical query log (paper §4.1's predictor)
         if history_queries is not None and len(history_queries):
@@ -373,10 +381,12 @@ class MemANNSEngine:
             return self._dev_arrays
         spec_dev, spec_rep = self._sharding_specs()
         s = self.shards
-        # one batched transfer for the whole pytree (5 sharded + 1 replicated)
+        # one batched transfer for the whole pytree (5 sharded + 1 replicated);
+        # codes ship column-major (ndev, W, cap), the layout the scan kernels
+        # stream lane-dense
         self._dev_arrays = jax.device_put(
             (
-                s.codes,
+                np.ascontiguousarray(s.codes.transpose(0, 2, 1)),
                 s.vec_ids,
                 s.slot_start,
                 s.slot_size,
@@ -528,6 +538,7 @@ class MemANNSEngine:
         load_carry: np.ndarray | None = None,
         prune: bool | None = None,
         live: np.ndarray | None = None,
+        query_width: int | None = None,
     ) -> SearchPlan:
         """Host-side online phase: filter + schedule + array densify.
 
@@ -552,6 +563,11 @@ class MemANNSEngine:
         Unreachable clusters are also zeroed out of the warm-start size
         accounting — a bound may only count rows the scan will actually
         visit, otherwise degraded queries could prune reportable rows.
+
+        `query_width` (default `nprobe`) is the per-device width of the
+        plan's query->pair index; it is part of the executable's shape, so
+        a caller planning at several nprobe values passes the largest to
+        keep one executable per pair bucket.
         """
         queries = np.asarray(queries, np.float32)
         q_n = queries.shape[0]
@@ -573,6 +589,9 @@ class MemANNSEngine:
         with tr.span("densify", root=False):
             pair_q, pair_slot, pair_valid = densify_schedule(
                 schedule, self.shards.local_slot, pairs_per_dev
+            )
+            query_pairs = query_pair_index(
+                pair_q, pair_valid, q_n, query_width or nprobe
             )
             order, d_sorted, pos = schedule.device_positions()
             pq, pc = schedule.pair_q[order], schedule.pair_c[order]
@@ -639,6 +658,7 @@ class MemANNSEngine:
             schedule=schedule,
             n_queries=q_n,
             pairs_per_dev=pairs_per_dev,
+            query_pairs=query_pairs,
             tile_pair=tile_pair,
             tile_block=tile_block,
             tile_row0=tile_row0,
@@ -700,6 +720,26 @@ class MemANNSEngine:
         The scan variant comes from the *plan* (a tiles plan carries its
         tile queue), so plans stay executable even if `self.scan` changes.
         """
+        args, static, query_bound = self._step_inputs(plan, k)
+        out_d, out_i, prune_stats = sharded_search(*args, **static)
+        return InFlightSearch(
+            out_d=out_d, out_i=out_i, plan=plan,
+            dev_rows=self.plan_dev_rows(plan),
+            prune_stats=prune_stats,
+            query_bound=query_bound,
+        )
+
+    def compiled_search_text(self, plan: SearchPlan, k: int) -> str:
+        """HLO text of the compiled search step `plan` dispatches to.
+
+        Shows what runs on the device: a Pallas kernel compiled for the
+        chip appears as a `tpu_custom_call`."""
+        args, static, _ = self._step_inputs(plan, k)
+        return sharded_search.lower(*args, **static).compile().as_text()
+
+    def _step_inputs(self, plan: SearchPlan, k: int):
+        """(device arguments, static kwargs, host query bounds) of the
+        `sharded_search` step for `plan` at top-`k`."""
         dev = self._device_put()
         ndev = self.shards.ndev
         spec_dev, spec_rep = self._sharding_specs()
@@ -724,13 +764,12 @@ class MemANNSEngine:
         batch = jax.device_put(
             (
                 plan.qmc_pairs, plan.pair_q, plan.pair_slot, plan.pair_valid,
-                tile_pair, tile_block, tile_row0, pair_lb, query_bound,
+                plan.query_pairs, tile_pair, tile_block, tile_row0, pair_lb,
+                query_bound,
             ),
-            (spec_dev,) * 8 + (spec_rep,),
+            (spec_dev,) * 9 + (spec_rep,),
         )
-        out_d, out_i, prune_stats = sharded_search(
-            *dev,
-            *batch,
+        static = dict(
             mesh=self.mesh,
             n_queries=plan.n_queries,
             k=k,
@@ -741,12 +780,7 @@ class MemANNSEngine:
             scan=plan.scan,
             interpret=self.interpret,
         )
-        return InFlightSearch(
-            out_d=out_d, out_i=out_i, plan=plan,
-            dev_rows=self.plan_dev_rows(plan),
-            prune_stats=prune_stats,
-            query_bound=query_bound,
-        )
+        return (*dev, *batch), static, query_bound
 
     def _raw_device_put(self):
         """Shard the raw-vector store over the mesh once, cache on device.

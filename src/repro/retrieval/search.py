@@ -25,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ops
+from repro.kernels.lut_build import ext_lut_pairs_kernel
 
 DPU_AXIS = "dpu"
 
@@ -68,27 +69,14 @@ class InFlightSearch:
         Non-blocking (`jax.Array.is_ready`), so the serving layer's
         collect timeout can poll for completion and turn a hung device
         into a fault event instead of blocking forever in `collect`.
-        Runtimes without `is_ready` report True (collect blocks as
-        before -- no watchdog, but no behavior change either).
         """
-        try:
-            return bool(self.out_d.is_ready() and self.out_i.is_ready())
-        except AttributeError:
-            return True
+        return bool(self.out_d.is_ready() and self.out_i.is_ready())
 
 
 def _shard_map(fn, mesh, in_specs, out_specs):
-    """jax.shard_map across jax versions (experimental module + kwarg rename)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-    from jax.experimental.shard_map import shard_map as sm
-
-    return sm(
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False,
+        check_vma=False,
     )
 
 
@@ -104,20 +92,22 @@ def search_static_key(
     add_offsets: bool,
     scan: str = "windows",
     tiles_per_dev: int = 0,
+    query_width: int = 0,
 ) -> tuple:
     """Compilation-cache key of one `sharded_search` instance.
 
     Two calls whose keys match hit the same jitted executable; the serving
     layer tracks warmed keys with this to guarantee steady-state batches
     never recompile.  `tiles_per_dev` is the tile-list capacity (0 on the
-    windows path, where the dummy tile arrays have a fixed width of 1).
+    windows path, where the dummy tile arrays have a fixed width of 1);
+    `query_width` is the width S of the plan's query->pair index.
     """
     return (ndev, n_queries, pairs_per_dev, k, block_n, window, path,
-            add_offsets, scan, tiles_per_dev)
+            add_offsets, scan, tiles_per_dev, query_width)
 
 
 def _device_search(
-    codes,        # (cap, W) int32        [device-local]
+    codes_t,      # (W, cap) codes, column-major  [device-local]
     vec_ids,      # (cap,) int32          [device-local]
     slot_start,   # (S,) int32            [device-local]
     slot_size,    # (S,) int32            [device-local]
@@ -127,6 +117,7 @@ def _device_search(
     pair_q,       # (P,) int32
     pair_slot,    # (P,) int32
     pair_valid,   # (P,) bool
+    query_pairs,  # (Q, S) int32 each query's pair slots, P = none
     tile_pair,    # (T,) int32            [device-local; (1,) dummy on windows]
     tile_block,   # (T,) int32
     tile_row0,    # (T,) int32
@@ -147,25 +138,18 @@ def _device_search(
     dsub = codebook.shape[2]
 
     # --- stage (b): LUT construction on device ------------------------------
-    luts = ops.build_luts(
-        codebook, qmc.reshape(p, m, dsub), interpret=interpret
-    )  # (P, M, 256)
+    luts = ops.build_luts(codebook, qmc.reshape(p, m, dsub))  # (P, M, 256)
     if combo_addrs.shape[1] > 0:
         pair_combos = combo_addrs[pair_slot]  # (P, m_combos, L)
-        from repro.kernels.lut_build import ext_lut_pairs_kernel
-
-        t_pad = m * 256 + combo_addrs.shape[1] + 1
+        t_pad = -(-(m * 256 + combo_addrs.shape[1] + 1) // ops.LANE) * ops.LANE
         tables = ext_lut_pairs_kernel(
-            luts,
-            pair_combos,
-            t_pad=t_pad,
-            interpret=bool(interpret)
-            if interpret is not None
-            else jax.default_backend() != "tpu",
+            luts, pair_combos, t_pad=t_pad,
+            interpret=ops.interpret_mode(interpret),
         )  # (P, A)
     else:
-        zero = jnp.zeros((p, 1), luts.dtype)
-        tables = jnp.concatenate([luts.reshape(p, -1), zero], axis=-1)
+        # plain codes never address past the LUT: the kernels read any
+        # address beyond the table as 0, so no sentinel slot is appended
+        tables = luts.reshape(p, -1)
 
     # --- stages (c)+(d): per-pair fused scan + top-k ------------------------
     # both variants stream blocks of the shared code array via scalar
@@ -175,21 +159,14 @@ def _device_search(
     n_valid = jnp.where(pair_valid, slot_size[pair_slot], 0)
     if scan == "tiles":
         tv, ti, prune = ops.adc_topk_tiles(
-            tables, codes, tile_pair, tile_block, tile_row0, n_valid, k,
+            tables, codes_t, tile_pair, tile_block, tile_row0, n_valid, k,
             block_n=block_n, path=path, add_offsets=add_offsets,
             interpret=interpret, pair_q=pair_q, pair_lb=pair_lb,
             bound=query_bound, n_queries=n_queries, with_stats=True,
-        )  # per-pair top-k sliced from the (P+1, k) scratch
-        # pairs that emitted no tiles have undefined output rows; mask to
-        # the windows kernel's init values so both paths stay bit-identical
-        # (their prune-stat rows are equally undefined -> masked to zero)
-        empty = (n_valid <= 0)[:, None]
-        tv = jnp.where(empty, jnp.inf, tv)
-        ti = jnp.where(empty, -1, ti)
-        prune = jnp.where(empty, 0, prune)
+        )  # pairs without tiles come back (inf, -1) with zero stats
     else:
         tv, ti, prune = ops.adc_topk_windows(
-            tables, codes, starts, n_valid, k,
+            tables, codes_t, starts, n_valid, k,
             window=window, block_n=block_n, path=path,
             add_offsets=add_offsets, interpret=interpret,
             pair_q=pair_q, pair_lb=pair_lb,
@@ -197,19 +174,29 @@ def _device_search(
         )  # (P, k) dists, (P, k) window-row idx, (P, 2) prune counters
     prune_dev = prune.sum(axis=0).reshape(1, 2)  # (1, 2) device totals
 
-    rows = starts[:, None] + ti                     # (P, k) device rows
-    gids = jnp.where(ti >= 0, vec_ids[jnp.clip(rows, 0, None)], -1)
     tv = jnp.where(pair_valid[:, None], tv, jnp.inf)
+    # device row of each candidate; slots past a pair's candidates (+inf)
+    # carry -1
+    rows = jnp.where(
+        (ti >= 0) & jnp.isfinite(tv), starts[:, None] + ti, -1
+    )                                               # (P, k)
 
     # --- per-query local merge (thread-local heap merge analogue) -----------
-    qsel = pair_q[None, :] == jnp.arange(n_queries)[:, None]   # (Q, P)
-    bd = jnp.where(qsel[:, :, None], tv[None], jnp.inf)        # (Q, P, k)
-    bi = jnp.broadcast_to(gids[None], bd.shape)
-    bd = bd.reshape(n_queries, -1)
-    bi = bi.reshape(n_queries, -1)
-    neg, sel = jax.lax.top_k(-bd, k)                           # (Q, k)
+    # gather each query's pair lists (row P: the (+inf, -1) dummy) in
+    # ascending slot order, so top_k's lowest-index tie-break is the
+    # (pair slot, rank) order of a merge over every pair of the device
+    tv = jnp.concatenate([tv, jnp.full((1, k), jnp.inf, tv.dtype)])
+    rows = jnp.concatenate([rows, jnp.full((1, k), -1, rows.dtype)])
+    cand_d = tv[query_pairs].reshape(n_queries, -1)            # (Q, S*k)
+    cand_r = rows[query_pairs].reshape(n_queries, -1)
+    neg, sel = jax.lax.top_k(-cand_d, k)                       # (Q, k)
     local_d = -neg
-    local_i = jnp.take_along_axis(bi, sel, axis=-1)
+    local_r = jnp.take_along_axis(cand_r, sel, axis=-1)
+    # global ids for the Q * k survivors only (a gather over every pair's
+    # rows compiles for tens of seconds on TPU)
+    local_i = jnp.where(
+        local_r >= 0, vec_ids[jnp.clip(local_r, 0, None)], -1
+    )
 
     # --- global merge over the 'dpu' axis ------------------------------------
     all_d = jax.lax.all_gather(local_d, DPU_AXIS, axis=0)      # (ndev, Q, k)
@@ -337,8 +324,8 @@ def sharded_rerank(
     ),
 )
 def sharded_search(
-    codes, vec_ids, slot_start, slot_size, combo_addrs,
-    codebook, qmc, pair_q, pair_slot, pair_valid,
+    codes_t, vec_ids, slot_start, slot_size, combo_addrs,
+    codebook, qmc, pair_q, pair_slot, pair_valid, query_pairs,
     tile_pair, tile_block, tile_row0,
     pair_lb, query_bound,
     *,
@@ -353,6 +340,10 @@ def sharded_search(
     interpret: bool | None = None,
 ):
     """shard_map wrapper: leading dim of device arrays is the 'dpu' axis.
+
+    `codes_t` is the column-major code array, (ndev, W, cap);
+    `query_pairs` ((ndev, Q, S) int32, `core.scheduling.query_pair_index`)
+    lists each query's pair slots per device for the per-query merge.
 
     `scan` selects the device scan variant: "windows" (padded per-pair
     windows) or "tiles" (flat work queue; `tile_*` are (ndev, T) arrays
@@ -373,29 +364,23 @@ def sharded_search(
         scan=scan, interpret=interpret,
     )
 
-    def per_device(codes, vec_ids, slot_start, slot_size, combo_addrs,
-                   codebook, qmc, pair_q, pair_slot, pair_valid,
-                   tile_pair, tile_block, tile_row0, pair_lb, query_bound):
-        # strip the leading (size-1) shard dim
-        return fn(
-            codes[0], vec_ids[0], slot_start[0], slot_size[0], combo_addrs[0],
-            codebook, qmc[0], pair_q[0], pair_slot[0], pair_valid[0],
-            tile_pair[0], tile_block[0], tile_row0[0],
-            pair_lb[0], query_bound,
-        )
+    def per_device(*args):
+        # strip the leading (size-1) shard dim of the sharded arguments
+        return fn(*(a if s is spec_rep else a[0] for a, s in zip(args, specs)))
 
+    specs = (
+        spec_dev, spec_dev, spec_dev, spec_dev, spec_dev,
+        spec_rep, spec_dev, spec_dev, spec_dev, spec_dev, spec_dev,
+        spec_dev, spec_dev, spec_dev, spec_dev, spec_rep,
+    )
     return _shard_map(
         per_device,
         mesh=mesh,
-        in_specs=(
-            spec_dev, spec_dev, spec_dev, spec_dev, spec_dev,
-            spec_rep, spec_dev, spec_dev, spec_dev, spec_dev,
-            spec_dev, spec_dev, spec_dev, spec_dev, spec_rep,
-        ),
+        in_specs=specs,
         out_specs=(spec_rep, spec_rep, spec_dev),
     )(
-        codes, vec_ids, slot_start, slot_size, combo_addrs,
-        codebook, qmc, pair_q, pair_slot, pair_valid,
+        codes_t, vec_ids, slot_start, slot_size, combo_addrs,
+        codebook, qmc, pair_q, pair_slot, pair_valid, query_pairs,
         tile_pair, tile_block, tile_row0,
         pair_lb, query_bound,
     )

@@ -749,6 +749,7 @@ class ServingEngine:
             add_offsets=s.add_offsets,
             scan=plan.scan,
             tiles_per_dev=plan.tiles_per_dev,
+            query_width=plan.query_pairs.shape[2],
         ) + (s.codes.shape, s.slot_start.shape[1])
 
     def _delta_key(self) -> tuple:
@@ -870,6 +871,10 @@ class ServingEngine:
             schedule=None,
             n_queries=self.micro_batch,
             pairs_per_dev=pairs_per_dev,
+            query_pairs=np.full(
+                (ndev, self.micro_batch, self.nprobe), pairs_per_dev,
+                np.int32,
+            ),
             tile_pair=tile_pair,
             tile_block=tile_block,
             tile_row0=tile_row0,
@@ -1044,6 +1049,9 @@ class ServingEngine:
             capacity_floor=self.capacity_floor,
             load_carry=self._load_ewma if self.load_feedback else None,
             live=self._live_arg(),
+            # degraded plans keep the full-nprobe index width: same
+            # executables as the healthy path
+            query_width=self.nprobe,
         )
 
     def _delta_micro_batch(

@@ -97,7 +97,7 @@ def test_adc_topk_windows():
     starts = jnp.asarray((RNG.integers(0, (cap - window) // bn, p) * bn).astype(np.int32))
     n_valid = jnp.asarray(RNG.integers(1, window, (p,)).astype(np.int32))
     tv, ti = ops.adc_topk_windows(
-        tables, codes, starts, n_valid, k, window=window, block_n=bn
+        tables, codes.T, starts, n_valid, k, window=window, block_n=bn
     )
     for i in range(p):
         win = codes[starts[i] : starts[i] + window]
@@ -124,7 +124,7 @@ def test_adc_topk_windows_compact_dtypes(dtype):
     sizes = jnp.asarray(RNG.integers(1, window, (p,)).astype(np.int32))
     starts = jnp.asarray((np.arange(p) * 3 * bn).astype(np.int32))
     tv, ti, _ = adc_topk_windows_kernel(
-        tables, codes, starts // bn, sizes, k=k, window=window,
+        tables, codes.T, starts // bn, sizes, k=k, window=window,
         block_n=bn, add_offsets=add_offsets, interpret=True,
     )
     for i in range(p):
@@ -161,7 +161,7 @@ def test_adc_topk_tiles():
     tb_ += [0, 0]
     tr_ += [0, 0]
     tv, ti, _ = adc_topk_tiles_kernel(
-        tables, codes, jnp.asarray(tp_), jnp.asarray(tb_), jnp.asarray(tr_),
+        tables, codes.T, jnp.asarray(tp_), jnp.asarray(tb_), jnp.asarray(tr_),
         jnp.asarray(sizes), k=k, block_n=bn, add_offsets=True, interpret=True,
     )
     for i in range(p):

@@ -288,3 +288,33 @@ def test_compaction_report_fields(base_engine, clustered_data):
     assert rep.devices_rewritten >= 1
     assert not rep.shapes_changed  # the build slack absorbed one row
     assert "compaction" in rep.summary()
+
+
+def test_delta_topk_block_pads_when_buffer_smaller_than_k():
+    """A delta buffer holding fewer rows than k returns its rows plus
+    (+inf, -1) padding instead of failing in top_k."""
+    import jax.numpy as jnp
+
+    from repro.core.delta import delta_topk_block
+
+    rng = np.random.default_rng(3)
+    c, m, dsub, cap, k = 4, 4, 2, 8, 12
+    centroids = rng.normal(0, 5, (c, m * dsub)).astype(np.float32)
+    codebook = rng.normal(0, 1, (m, 256, dsub)).astype(np.float32)
+    queries = centroids[:2] + 0.1
+    ids = np.arange(100, 100 + cap, dtype=np.int32)
+    alive = np.arange(cap) < 5
+    d, i = delta_topk_block(
+        jnp.asarray(centroids), jnp.asarray(codebook), jnp.asarray(queries),
+        jnp.asarray(rng.integers(0, 256, (cap, m)).astype(np.uint8)),
+        jnp.asarray(np.arange(cap, dtype=np.int32) % c),
+        jnp.asarray(ids), jnp.asarray(alive),
+        jnp.full((2,), jnp.inf, jnp.float32), nprobe=c, k=k,
+    )
+    d, i = np.asarray(d), np.asarray(i)
+    assert d.shape == i.shape == (2, k)
+    for q in range(2):
+        assert sorted(i[q, :5].tolist()) == ids[:5].tolist()
+        assert np.all(np.isfinite(d[q, :5]))
+        assert np.all(np.diff(d[q, :5]) >= 0)
+        assert np.all(np.isinf(d[q, 5:])) and np.all(i[q, 5:] == -1)

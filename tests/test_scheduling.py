@@ -193,3 +193,25 @@ def test_densify_overflow_raises():
     cap = int(vec.counts_per_dev().max())
     with pytest.raises(ValueError, match="capacity"):
         densify_schedule(vec, local_slot, cap - 1)
+
+
+def test_query_pair_index_lists_each_querys_slots():
+    """Each (device, query) row lists that query's valid pair slots in
+    ascending order, padded with P; padding pairs never appear."""
+    from repro.core.scheduling import query_pair_index
+
+    pair_q = np.asarray([[2, 0, 2, 1, 0, 0], [1, 1, 0, 0, 0, 0]], np.int32)
+    valid = np.asarray(
+        [[1, 1, 1, 1, 1, 0], [1, 1, 0, 0, 0, 0]], bool
+    )
+    qp = query_pair_index(pair_q, valid, n_queries=3, width=3)
+    p = pair_q.shape[1]
+    np.testing.assert_array_equal(
+        qp,
+        [
+            [[1, 4, p], [3, p, p], [0, 2, p]],
+            [[p, p, p], [0, 1, p], [p, p, p]],
+        ],
+    )
+    with pytest.raises(ValueError, match="query index width"):
+        query_pair_index(pair_q, valid, n_queries=3, width=1)

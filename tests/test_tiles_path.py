@@ -175,7 +175,7 @@ def test_tiles_kernel_matches_ref():
     )
 
     tv, ti = ops.adc_topk_tiles(
-        tables, jnp.asarray(codes), tile_pair, tile_block, tile_row0,
+        tables, jnp.asarray(codes.T), tile_pair, tile_block, tile_row0,
         jnp.asarray(n_valid), k, block_n=bn, add_offsets=True,
         interpret=True,
     )
@@ -212,7 +212,7 @@ def test_all_dummy_tile_list_masks_to_windows_contract():
     tile_row0 = jnp.zeros((t_cap,), jnp.int32)
 
     tv, ti, _ = adc_topk_tiles_kernel(
-        tables, jnp.asarray(codes), tile_pair, tile_block, tile_row0,
+        tables, jnp.asarray(codes.T), tile_pair, tile_block, tile_row0,
         n_valid, k=k, block_n=bn, add_offsets=True, interpret=True,
     )
     # apply the documented caller-side mask for pairs with no tiles
@@ -220,7 +220,7 @@ def test_all_dummy_tile_list_masks_to_windows_contract():
     ti = jnp.where((n_valid <= 0)[:, None], -1, ti)
 
     wv, wi, _ = adc_topk_windows_kernel(
-        tables, jnp.asarray(codes),
+        tables, jnp.asarray(codes.T),
         (jnp.asarray(starts[:p]) // bn).astype(jnp.int32), n_valid,
         k=k, window=2 * bn, block_n=bn, add_offsets=True, interpret=True,
     )
@@ -248,11 +248,11 @@ def test_all_dummy_tile_list_pruned_matches_unpruned():
     tile_row0 = jnp.zeros((6,), jnp.int32)
     kw = dict(k=k, block_n=bn, add_offsets=True, interpret=True)
     tv, ti = ops.adc_topk_tiles(
-        tables, jnp.asarray(codes), tile_pair, tile_block, tile_row0,
+        tables, jnp.asarray(codes.T), tile_pair, tile_block, tile_row0,
         n_valid, **kw,
     )
     tvp, tip, stats = ops.adc_topk_tiles(
-        tables, jnp.asarray(codes), tile_pair, tile_block, tile_row0,
+        tables, jnp.asarray(codes.T), tile_pair, tile_block, tile_row0,
         n_valid,
         pair_q=jnp.asarray([0, 1, 0, 1], jnp.int32),
         pair_lb=jnp.zeros((p,), jnp.float32),
