@@ -1,0 +1,16 @@
+"""The benchmark's own tests, run by hand on the CPU:
+
+    python -m pytest bench/tests
+
+They import the harness's modules from `bench/` and the program from
+`src/`, and never look for a chip."""
+
+import os
+import sys
+from pathlib import Path
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+BENCH = Path(__file__).resolve().parents[1]
+for p in (BENCH, BENCH.parent / "src"):
+    if str(p) not in sys.path:
+        sys.path.insert(0, str(p))
