@@ -1,0 +1,11 @@
+"""Share of the tiles scan's grid steps in the window that filled the tile
+bucket's capacity with dummy tiles (program counter
+`upanns_scan_steps_total`, kind `bucket`, over all its kinds;
+`program_counters.py`)."""
+
+import program_counters
+
+
+def read(ctx):
+    steps = program_counters.scan_steps()
+    return None if steps is None else 100.0 * steps[2] / sum(steps)

@@ -92,6 +92,15 @@ BIG = jnp.iinfo(jnp.int32).max
 TILE_CHUNK = 8192
 
 
+def tile_queue_chunks(t_n: int) -> tuple[int, int]:
+    """(chunk, n_chunks) the tiles scan splits a queue of `t_n` tiles into.
+
+    The scan runs one grid step per tile of `n_chunks * chunk`: the queue
+    is padded with dummy tiles to whole chunks."""
+    chunk = max(1, min(t_n, TILE_CHUNK))
+    return chunk, -(-t_n // chunk)
+
+
 def merge_topk(
     cur_v: jax.Array,
     cur_i: jax.Array,
@@ -430,8 +439,7 @@ def adc_topk_tiles_kernel(
         bound = jnp.full((n_queries,), jnp.inf, jnp.float32)
     # pad the queue to whole chunks with dummy tiles (pair P: no valid
     # rows, lb = +inf -> they always prune away)
-    chunk = max(1, min(t_n, TILE_CHUNK))
-    n_chunks = -(-t_n // chunk)
+    chunk, n_chunks = tile_queue_chunks(t_n)
     pad = n_chunks * chunk - t_n
 
     def padded(x, fill):

@@ -21,6 +21,7 @@ from repro.obs.metrics import (
     Histogram,
     MetricsRegistry,
     NULL_REGISTRY,
+    PROCESS_REGISTRY,
     NullRegistry,
 )
 from repro.obs.trace import NULL_SPAN, NULL_TRACER, Span, Tracer
@@ -33,6 +34,7 @@ __all__ = [
     "MetricsRegistry",
     "NullRegistry",
     "NULL_REGISTRY",
+    "PROCESS_REGISTRY",
     "Span",
     "Tracer",
     "NULL_SPAN",

@@ -33,6 +33,13 @@ constraints, in order:
 `NULL_REGISTRY` is the do-nothing twin (`ServingEngine(metrics=False)`);
 it keeps every call site branch-free while making "observability off"
 measurable (see the ``qps_obs_overhead`` bench row).
+
+`PROCESS_REGISTRY` is the process-lifetime view, as a Prometheus client's
+default registry is: a family an engine also adds there is summed over
+every engine the process has run and outlives them, so a caller that
+never held the engine (a benchmark reader run after the engine is freed)
+can still read it.  Which families go there is listed in
+docs/OBSERVABILITY.md.
 """
 
 from __future__ import annotations
@@ -455,3 +462,5 @@ class NullRegistry:
 
 
 NULL_REGISTRY = NullRegistry()
+
+PROCESS_REGISTRY = MetricsRegistry()

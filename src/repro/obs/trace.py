@@ -33,7 +33,9 @@ steady-state recompiles with tracing on vs off.
 ``Tracer(profiler=True)`` additionally brackets every recorded span in a
 ``jax.profiler.TraceAnnotation`` so spans line up with XLA's own timeline
 when a jax profile is being captured (opt-in: the import and the
-annotation objects cost more than the spans themselves).
+annotation objects cost more than the spans themselves).  The span's args
+go with it, as the annotation's stats: the ``dispatch`` span of a batch
+shows its scan grid steps in the device trace.
 """
 
 from __future__ import annotations
@@ -111,7 +113,7 @@ class _SpanCtx:
         tr = self._tracer
         tr._stack_of().append(self._span)
         if tr.profiler:
-            self._annotation = tr._annotate(self._span.name)
+            self._annotation = tr._annotate(self._span.name, self._span.args)
         return self._span
 
     def __exit__(self, *exc) -> bool:
@@ -136,7 +138,8 @@ class Tracer:
         accumulator sampling: exactly ``round(n * sample)`` of n batches
         record, independent of timing, so twin runs sample identically.
       profiler: bracket every recorded span in a
-        ``jax.profiler.TraceAnnotation`` (opt-in; needs jax importable).
+        ``jax.profiler.TraceAnnotation`` carrying the span's args (opt-in;
+        needs jax importable).
     """
 
     def __init__(self, ring: int = 1024, sample: float = 1.0,
@@ -159,11 +162,11 @@ class Tracer:
             stack = self._local.stack = []
         return stack
 
-    def _annotate(self, name: str):
+    def _annotate(self, name: str, args: dict):
         try:
             import jax.profiler
 
-            ann = jax.profiler.TraceAnnotation(name)
+            ann = jax.profiler.TraceAnnotation(name, **args)
             ann.__enter__()
             return ann
         except Exception:  # profiler unavailable: spans still record

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.index import IVFPQIndex, build_index, filter_clusters
+from repro.kernels.adc_topk import tile_queue_chunks
 from repro.obs.trace import NULL_TRACER
 from repro.core.placement import (
     Placement,
@@ -707,6 +708,38 @@ class MemANNSEngine:
         )
         bn = self.shards.block_n
         return int(((nv + bn - 1) // bn).sum())
+
+    def plan_scan_steps(
+        self, plan: SearchPlan, q_real: int
+    ) -> tuple[int, int, int]:
+        """(query, pad, bucket): the scan kernel's grid steps for `plan`,
+        summed over devices, by what each step serves.
+
+        `query` steps serve one of the first `q_real` queries of the plan
+        (the real rows of a padded micro-batch), `pad` steps serve its
+        padding rows, and `bucket` steps fill capacity: on the tiles path
+        the dummy tiles of the tile bucket plus the kernel's padding of
+        the queue to whole chunks, on the windows path the invalid pairs.
+        The three add up to the kernel's grid steps.  Host arrays only.
+        """
+        p = plan.pairs_per_dev
+        if plan.scan == "tiles":
+            tp = plan.tile_pair
+            real = tp != p
+            tile_q = np.take_along_axis(
+                plan.pair_q, np.minimum(tp, p - 1), axis=1
+            )
+            query = int((real & (tile_q < q_real)).sum())
+            pad = int(real.sum()) - query
+            chunk, n_chunks = tile_queue_chunks(tp.shape[1])
+            total = tp.shape[0] * chunk * n_chunks
+        else:
+            per_pair = self.shards.window // self.shards.block_n
+            valid = plan.pair_valid
+            query = int((valid & (plan.pair_q < q_real)).sum()) * per_pair
+            pad = int(valid.sum()) * per_pair - query
+            total = valid.size * per_pair
+        return query, pad, total - query - pad
 
     def dispatch_plan(self, plan: SearchPlan, k: int) -> InFlightSearch:
         """Enqueue one shard_map step without blocking on its results.

@@ -138,18 +138,23 @@ def _device_search(
     dsub = codebook.shape[2]
 
     # --- stage (b): LUT construction on device ------------------------------
-    luts = ops.build_luts(codebook, qmc.reshape(p, m, dsub))  # (P, M, 256)
-    if combo_addrs.shape[1] > 0:
-        pair_combos = combo_addrs[pair_slot]  # (P, m_combos, L)
-        t_pad = -(-(m * 256 + combo_addrs.shape[1] + 1) // ops.LANE) * ops.LANE
-        tables = ext_lut_pairs_kernel(
-            luts, pair_combos, t_pad=t_pad,
-            interpret=ops.interpret_mode(interpret),
-        )  # (P, A)
-    else:
-        # plain codes never address past the LUT: the kernels read any
-        # address beyond the table as 0, so no sentinel slot is appended
-        tables = luts.reshape(p, -1)
+    # (named scopes label the stages' XLA ops in a device profile's op
+    # metadata; they change no instruction)
+    with jax.named_scope("lut"):
+        luts = ops.build_luts(codebook, qmc.reshape(p, m, dsub))  # (P, M, 256)
+        if combo_addrs.shape[1] > 0:
+            pair_combos = combo_addrs[pair_slot]  # (P, m_combos, L)
+            t_pad = (
+                -(-(m * 256 + combo_addrs.shape[1] + 1) // ops.LANE) * ops.LANE
+            )
+            tables = ext_lut_pairs_kernel(
+                luts, pair_combos, t_pad=t_pad,
+                interpret=ops.interpret_mode(interpret),
+            )  # (P, A)
+        else:
+            # plain codes never address past the LUT: the kernels read any
+            # address beyond the table as 0, so no sentinel slot is appended
+            tables = luts.reshape(p, -1)
 
     # --- stages (c)+(d): per-pair fused scan + top-k ------------------------
     # both variants stream blocks of the shared code array via scalar
@@ -185,28 +190,30 @@ def _device_search(
     # gather each query's pair lists (row P: the (+inf, -1) dummy) in
     # ascending slot order, so top_k's lowest-index tie-break is the
     # (pair slot, rank) order of a merge over every pair of the device
-    tv = jnp.concatenate([tv, jnp.full((1, k), jnp.inf, tv.dtype)])
-    rows = jnp.concatenate([rows, jnp.full((1, k), -1, rows.dtype)])
-    cand_d = tv[query_pairs].reshape(n_queries, -1)            # (Q, S*k)
-    cand_r = rows[query_pairs].reshape(n_queries, -1)
-    neg, sel = jax.lax.top_k(-cand_d, k)                       # (Q, k)
-    local_d = -neg
-    local_r = jnp.take_along_axis(cand_r, sel, axis=-1)
-    # global ids for the Q * k survivors only (a gather over every pair's
-    # rows compiles for tens of seconds on TPU)
-    local_i = jnp.where(
-        local_r >= 0, vec_ids[jnp.clip(local_r, 0, None)], -1
-    )
+    with jax.named_scope("local_merge"):
+        tv = jnp.concatenate([tv, jnp.full((1, k), jnp.inf, tv.dtype)])
+        rows = jnp.concatenate([rows, jnp.full((1, k), -1, rows.dtype)])
+        cand_d = tv[query_pairs].reshape(n_queries, -1)        # (Q, S*k)
+        cand_r = rows[query_pairs].reshape(n_queries, -1)
+        neg, sel = jax.lax.top_k(-cand_d, k)                   # (Q, k)
+        local_d = -neg
+        local_r = jnp.take_along_axis(cand_r, sel, axis=-1)
+        # global ids for the Q * k survivors only (a gather over every
+        # pair's rows compiles for tens of seconds on TPU)
+        local_i = jnp.where(
+            local_r >= 0, vec_ids[jnp.clip(local_r, 0, None)], -1
+        )
 
     # --- global merge over the 'dpu' axis ------------------------------------
-    all_d = jax.lax.all_gather(local_d, DPU_AXIS, axis=0)      # (ndev, Q, k)
-    all_i = jax.lax.all_gather(local_i, DPU_AXIS, axis=0)
-    ndev = all_d.shape[0]
-    all_d = jnp.moveaxis(all_d, 0, 1).reshape(n_queries, ndev * k)
-    all_i = jnp.moveaxis(all_i, 0, 1).reshape(n_queries, ndev * k)
-    neg, sel = jax.lax.top_k(-all_d, k)
-    out_d = -neg
-    out_i = jnp.take_along_axis(all_i, sel, axis=-1)
+    with jax.named_scope("global_merge"):
+        all_d = jax.lax.all_gather(local_d, DPU_AXIS, axis=0)  # (ndev, Q, k)
+        all_i = jax.lax.all_gather(local_i, DPU_AXIS, axis=0)
+        ndev = all_d.shape[0]
+        all_d = jnp.moveaxis(all_d, 0, 1).reshape(n_queries, ndev * k)
+        all_i = jnp.moveaxis(all_i, 0, 1).reshape(n_queries, ndev * k)
+        neg, sel = jax.lax.top_k(-all_d, k)
+        out_d = -neg
+        out_i = jnp.take_along_axis(all_i, sel, axis=-1)
     return out_d, out_i, prune_dev
 
 
@@ -251,7 +258,8 @@ def _device_rerank(
     valid = (cand >= 0) & (owner >= 0)
     owned = valid & (owner == my)
     rows = jnp.where(owned, id_row[cid], 0)
-    vecs = raw[rows]                                     # (Q, Kc, D) gather
+    with jax.named_scope("rerank_gather"):
+        vecs = raw[rows]                                 # (Q, Kc, D) gather
     part = ops.rerank_dists(
         queries, vecs, block_k=block_k, interpret=interpret
     )
@@ -264,7 +272,8 @@ def _device_rerank(
     # tie-aware selection: stable sort by exact distance, ties broken by
     # ADC candidate position (so the cascade's output is deterministic and
     # matches the brute-force oracle's stable argsort bit-for-bit)
-    sel = jnp.argsort(dists, axis=-1, stable=True)[:, :k_out]
+    with jax.named_scope("rerank_select"):
+        sel = jnp.argsort(dists, axis=-1, stable=True)[:, :k_out]
     out_d = jnp.take_along_axis(dists, sel, axis=-1)
     out_i = jnp.take_along_axis(cand, sel, axis=-1)
     out_i = jnp.where(jnp.isfinite(out_d), out_i, -1)

@@ -60,7 +60,12 @@ import numpy as np
 
 from repro.core.delta import merge_results
 from repro.kernels import ops
-from repro.obs.metrics import NULL_REGISTRY, MetricsRegistry
+from repro.obs.metrics import (
+    NULL_REGISTRY,
+    PROCESS_REGISTRY,
+    MetricsRegistry,
+    NullRegistry,
+)
 from repro.obs.trace import NULL_SPAN, NULL_TRACER
 from repro.retrieval.engine import MemANNSEngine, SearchPlan, round_capacity
 from repro.retrieval.faults import DeviceHang, FaultError, TransientFault
@@ -79,11 +84,6 @@ from repro.retrieval.search import (
     search_static_key,
 )
 
-
-# per-batch latency samples retained for the percentile estimators; a
-# bounded window keeps long-running servers O(1)-memory while p50/p99
-# still reflect recent traffic
-LATENCY_WINDOW = 4096
 
 # per-batch lifecycle phases the serving layer times (the `phase` label
 # of `upanns_phase_seconds`; eagerly registered so exposition is
@@ -108,6 +108,11 @@ RETRY_PHASES = ("dispatch", "collect")
 # degraded service), "overloaded" (ingress queue full; admission control
 # is shedding).
 HEALTH_STATES = ("ok", "degraded", "overloaded")
+
+# what a grid step of the scan kernel serves (the `kind` label of
+# `upanns_scan_steps_total`, `MemANNSEngine.plan_scan_steps`): a real
+# query's tile, a padding row's tile, or a capacity dummy
+SCAN_STEP_KINDS = ("query", "pad", "bucket")
 
 
 @dataclasses.dataclass
@@ -137,11 +142,6 @@ class ServingStats:
       collect_wait_s: seconds spent blocked inside collect (residual
         device execution + result transfer) — the honest device-side
         component of per-batch latency under pipelining.
-      latencies_s: per-micro-batch plan→collect latency samples, last
-        `LATENCY_WINDOW` batches.  DEPRECATED as a percentile source (the
-        log-bucketed `upanns_batch_latency_seconds` histogram in
-        `registry` feeds `p50_s`/`p99_s`/`p999_s` now); kept one release
-        for callers that read the raw window.
       bucket_hits: {pairs_per_dev bucket: times dispatched} histogram.
       registry: the `repro.obs.metrics.MetricsRegistry` every counter
         above is mirrored into (machine-readable: Prometheus text via
@@ -149,17 +149,22 @@ class ServingStats:
         `repro.obs.metrics.NULL_REGISTRY` (or construct the serving layer
         with `metrics=False`) to disable.  The full metric catalog lives
         in docs/OBSERVABILITY.md and is drift-checked by
-        tools/check_metrics.py.
+        tools/check_metrics.py.  Percentiles (`latency_percentile`,
+        `prune_percentile`, `p50_s`/`p99_s`/`p999_s`) read its
+        log-bucketed histograms, and read 0.0 with metrics off.  Some
+        families live in the registry only, with no field here:
+        `upanns_scan_steps_total` (the scan kernel's grid steps by what
+        they serve), which is also added to
+        `repro.obs.metrics.PROCESS_REGISTRY` unless metrics are off.
 
     Scan / early-pruning telemetry:
       rows_scanned: total code rows visited by collected batches.
-      tiles_dispatched: non-empty code tiles handed to the kernels.
+      tiles_dispatched: real code tiles handed to the kernels, padding
+        rows' tiles included (`upanns_scan_steps_total` splits them).
       tiles_skipped: tile bodies the pruning-bound check skipped whole.
       rows_pruned: valid rows inside those skipped tiles.
       warm_bound_queries: real queries dispatched with a finite warm-start
         bound (the bound-availability gauge).
-      prune_fracs: per-batch skipped/dispatched tile fraction samples,
-        windowed like `latencies_s` (feeds `prune_percentile`).
 
     Re-rank cascade (rerank="exact" only):
       reranked_queries: real queries whose results went through the exact
@@ -215,12 +220,6 @@ class ServingStats:
     delta_occupancy: float = 0.0
     tombstones: int = 0
     compaction_s: list[float] = dataclasses.field(default_factory=list)
-    latencies_s: collections.deque = dataclasses.field(
-        default_factory=lambda: collections.deque(maxlen=LATENCY_WINDOW)
-    )
-    prune_fracs: collections.deque = dataclasses.field(
-        default_factory=lambda: collections.deque(maxlen=LATENCY_WINDOW)
-    )
     bucket_hits: dict[int, int] = dataclasses.field(default_factory=dict)
     registry: object = None
 
@@ -263,7 +262,17 @@ class ServingStats:
             "Code rows visited, per device", ("device",))
         self.m_tiles_dispatched = r.counter(
             "upanns_tiles_dispatched_total",
-            "Non-empty code tiles handed to the kernels")
+            "Real code tiles handed to the kernels, padding rows' included")
+        steps = ("upanns_scan_steps_total",
+                 "Scan kernel grid steps, by what they serve", ("kind",))
+        self.m_scan_steps = r.counter(*steps)
+        # its process-lifetime twin, which outlives this engine
+        self.m_scan_steps_process = (
+            NULL_REGISTRY if isinstance(r, NullRegistry)
+            else PROCESS_REGISTRY).counter(*steps)
+        for kind in SCAN_STEP_KINDS:  # eager: exposition order is stable
+            self.m_scan_steps.labels(kind=kind)
+            self.m_scan_steps_process.labels(kind=kind)
         self.m_tiles_skipped = r.counter(
             "upanns_tiles_skipped_total",
             "Tile bodies the pruning-bound check skipped whole, per device",
@@ -333,6 +342,13 @@ class ServingStats:
     # Each helper updates the legacy field AND its registry mirror, so the
     # two can never drift; serving code calls these instead of touching
     # either store directly.
+
+    def note_scan_steps(self, steps: tuple[int, int, int]) -> None:
+        """One batch's scan grid steps (`SCAN_STEP_KINDS` order), into
+        the engine's registry and the process-lifetime one."""
+        for kind, n in zip(SCAN_STEP_KINDS, steps):
+            self.m_scan_steps.inc(n, kind=kind)
+            self.m_scan_steps_process.inc(n, kind=kind)
 
     def note_compile(self) -> None:
         self.compiles += 1
@@ -413,14 +429,8 @@ class ServingStats:
     def prune_percentile(self, q: float) -> float:
         """Per-batch prune-effectiveness percentile (bound-tightening
         profile).  Histogram-backed (O(1) memory, rel. error <=
-        sqrt(GROWTH)-1); falls back to the deprecated deque window when
-        metrics are off."""
-        h = self.m_prune_frac.labels()
-        if h.count:
-            return h.quantile(q)
-        if not self.prune_fracs:
-            return 0.0
-        return float(np.percentile(np.asarray(self.prune_fracs), q))
+        sqrt(GROWTH)-1); 0.0 when metrics are off."""
+        return self.m_prune_frac.labels().quantile(q)
 
     def overlap_fraction(self) -> float:
         """Fraction of host planning time hidden behind in-flight batches."""
@@ -431,14 +441,8 @@ class ServingStats:
 
         Backed by the `upanns_batch_latency_seconds` log-bucketed histogram
         (lifetime, O(1) memory, relative error <= sqrt(GROWTH)-1 ~ 4.4%,
-        p999 as cheap as p50); falls back to the deprecated `latencies_s`
-        deque window when metrics are off."""
-        h = self.m_latency.labels()
-        if h.count:
-            return h.quantile(q)
-        if not self.latencies_s:
-            return 0.0
-        return float(np.percentile(np.asarray(self.latencies_s), q))
+        p999 as cheap as p50); 0.0 when metrics are off."""
+        return self.m_latency.labels().quantile(q)
 
     def phase_percentile(self, phase: str, q: float) -> float:
         """Per-batch percentile of one lifecycle phase (see `PHASES`)."""
@@ -524,6 +528,8 @@ class _Flight:
     k_fetch: int
     skip_rerank: bool        # deadline-degraded: cascade skipped
     deadline_late: bool
+    # the plan's scan grid steps (query, pad, bucket), for the counter
+    steps: tuple[int, int, int] = (0, 0, 0)
 
 
 class ServingEngine:
@@ -578,7 +584,7 @@ class ServingEngine:
         `repro.obs.metrics.MetricsRegistry` (`stats.registry`): Prometheus
         text / JSON exposition, histogram-backed p50/p99/p999.  `False`
         installs `NULL_REGISTRY` (every mirror call a no-op) and the
-        percentile estimators fall back to the legacy deque windows.
+        percentile estimators read 0.0.
       deadline_ms: per-search() latency budget in milliseconds (None =
         no deadline).  Micro-batches planned after the budget has elapsed
         are served DEGRADED — nprobe shrinks to `degrade_nprobe` and an
@@ -1206,6 +1212,7 @@ class ServingEngine:
                     raise
                 self._mark_dead(e.device)
                 plan = self._plan_micro_batch(fl.padded, nprobe=fl.nprobe_eff)
+                fl.steps = self.engine.plan_scan_steps(plan, fl.q_n)
                 attempts = 0
                 backoff = self.retry_backoff_s
                 escalations += 1
@@ -1264,6 +1271,7 @@ class ServingEngine:
         placement-invariant); the rest degrade with coverage accounting.
         """
         plan = self._plan_micro_batch(fl.padded, nprobe=fl.nprobe_eff)
+        fl.steps = self.engine.plan_scan_steps(plan, fl.q_n)
         self._dispatch_with_retry(fl, plan)
         fl.t_dispatched = time.perf_counter()
 
@@ -1285,7 +1293,7 @@ class ServingEngine:
         return self._collect_micro_batch(
             fl.handle, fl.q_n, fl.t_start, fl.mut, fl.t_dispatched,
             fl.bspan, deadline_late=fl.deadline_late,
-            skip_rerank=fl.skip_rerank,
+            skip_rerank=fl.skip_rerank, steps=fl.steps,
         )
 
     def health(self) -> dict:
@@ -1333,13 +1341,16 @@ class ServingEngine:
         *,
         deadline_late: bool = False,
         skip_rerank: bool = False,
+        steps: tuple[int, int, int] = (0, 0, 0),
     ) -> tuple[np.ndarray, np.ndarray]:
         """Block on one in-flight micro-batch; slice padding, record stats.
 
         `deadline_late` marks the batch as deadline-degraded (counted per
         real query); `skip_rerank` suppresses the cascade counters for a
         batch whose re-rank stage was deadline-skipped.  Coverage
-        degradation is read off the plan itself (`lost_q`).
+        degradation is read off the plan itself (`lost_q`).  `steps` are
+        the batch's scan grid steps (query, pad, bucket) counted at
+        dispatch (`MemANNSEngine.plan_scan_steps`).
 
         `mut` carries the batch's plan-time mutation snapshot
         (delta results + tombstones); the tombstone filter composes with
@@ -1368,7 +1379,6 @@ class ServingEngine:
         st.m_device.inc(t1 - t0)
         st.collect_wait_s += t1 - t0
         st.observe_phase("collect_wait", t1 - t0)
-        st.latencies_s.append(t1 - t_start)
         st.m_latency.observe(t1 - t_start)
         st.batches += 1
         st.m_batches.inc(scan=handle.plan.scan)
@@ -1380,7 +1390,7 @@ class ServingEngine:
             if dev_rows[dev]:
                 st.m_rows_scanned.inc(float(dev_rows[dev]), device=dev)
         # early-pruning effectiveness: skipped tile bodies vs dispatched
-        # tiles, per batch (windowed, the bound-tightening profile)
+        # tiles, per batch (the bound-tightening profile)
         tiles = self.engine.plan_tile_count(handle.plan)
         skipped = rows = 0
         if handle.prune_stats is not None:
@@ -1394,10 +1404,10 @@ class ServingEngine:
             skipped, rows = int(tot[0]), int(tot[1])
         st.tiles_dispatched += tiles
         st.m_tiles_dispatched.inc(tiles)
+        st.note_scan_steps(steps)
         st.tiles_skipped += skipped
         st.rows_pruned += rows
         frac = skipped / tiles if tiles else 0.0
-        st.prune_fracs.append(frac)
         st.m_prune_frac.observe(frac)
         if handle.plan.pruned and handle.query_bound is not None:
             # real (unpadded) queries dispatched with a finite warm start
@@ -1541,14 +1551,17 @@ class ServingEngine:
             st.observe_phase("plan", t1a - t0)
             if mutating:
                 st.observe_phase("delta", t1 - t1a)
+            steps = self.engine.plan_scan_steps(plan, chunk.shape[0])
             fl = _Flight(
                 handle=None, q_n=chunk.shape[0], offset=s, t_start=t0,
                 mut=mut, t_dispatched=None, bspan=bspan, seq=seq,
                 padded=padded, nprobe_eff=nprobe_eff, k_fetch=k_fetch,
-                skip_rerank=skip_rerank, deadline_late=late,
+                skip_rerank=skip_rerank, deadline_late=late, steps=steps,
             )
             with tr.span(
-                "dispatch", parent=bspan, pairs_per_dev=plan.pairs_per_dev
+                "dispatch", parent=bspan, pairs_per_dev=plan.pairs_per_dev,
+                tiles_per_dev=plan.tiles_per_dev, steps_query=steps[0],
+                steps_pad=steps[1], steps_bucket=steps[2],
             ):
                 self._dispatch_with_retry(fl, plan)
             t2 = time.perf_counter()

@@ -296,7 +296,9 @@ def test_zero_perturbation_ragged_stream(engine, clustered_data, scan):
     assert srv_on.stats.queries == 200 and srv_off.stats.queries == 200
     # the off side really is off: null registry, nothing rendered
     assert srv_off.stats.registry.render_prometheus() == ""
-    assert srv_off.stats.latency_percentile(50) >= 0.0  # deque fallback
+    # percentiles read the registry's histograms: 0.0 with metrics off
+    assert srv_off.stats.latency_percentile(50) == 0.0
+    assert srv_off.stats.prune_percentile(50) == 0.0
 
     # --- trace completeness: every query in exactly one batch tree --------
     roots = tracer.roots()
@@ -319,8 +321,11 @@ def test_zero_perturbation_ragged_stream(engine, clustered_data, scan):
 
 def test_histogram_backed_percentiles(engine, clustered_data):
     xs, _, qs, _ = clustered_data
-    srv = ServingEngine(engine, nprobe=NPROBE, k=K, micro_batch=8)
+    tracer = Tracer(sample=1.0)
+    srv = ServingEngine(engine, nprobe=NPROBE, k=K, micro_batch=8,
+                        tracer=tracer)
     srv.warmup()
+    tracer.clear()
     for _ in range(3):
         srv.search(qs)
     st = srv.stats
@@ -330,10 +335,15 @@ def test_histogram_backed_percentiles(engine, clustered_data):
     assert lo <= st.latency_percentile(50) <= hi
     assert st.p50_s() <= st.p99_s() + 1e-12
     assert st.p999_s() >= st.p99_s() - 1e-12
-    # deque window agrees with the sketch to the bucket-width budget
-    deque_p50 = float(np.percentile(np.asarray(st.latencies_s), 50))
+    # the exact p50 of the batch trees' durations (each tree spans its
+    # batch's plan->collect sample, plus the span bookkeeping around it)
+    # lies within one bucket of the histogram's enclosure
+    roots = [r for r in tracer.roots() if r.name == "batch"]
+    assert len(roots) == st.batches
+    exact_p50 = _true_rank_value([r.t1 - r.t0 for r in roots], 50.0)
+    assert lo / GROWTH <= exact_p50 <= hi * GROWTH
     assert st.latency_percentile(50) == pytest.approx(
-        deque_p50, rel=2 * (math.sqrt(GROWTH) - 1) + 0.01
+        exact_p50, rel=2 * (math.sqrt(GROWTH) - 1) + 0.01
     )
 
 

@@ -84,7 +84,7 @@ def test_pipeline_depth_bit_identical_300_query_stream(
         results[depth] = _drive(srv, ops)
         assert srv.stats.compiles == 0, (depth, srv.stats)
         assert srv.stats.queries == 300
-        assert len(srv.stats.latencies_s) == srv.stats.batches
+        assert srv.stats.m_latency.labels().count == srv.stats.batches
         assert srv.stats.rows_scanned > 0
         if depth == 0:
             assert srv.stats.overlap_s == 0.0
