@@ -3,10 +3,11 @@
 This is the TPU adaptation of paper §4.2 (thread pipeline) + §4.4 (top-k
 pruning): instead of thread-local heaps merged through semaphores, each grid
 step scans one (W, block_n) tile of codes and folds it into a k-sized running
-result held in VMEM.  The paper's pruning rule survives verbatim: if
-the tile's minimum distance is not below the current k-th best, the entire
-merge is skipped (`pl.when`), which is exactly "the remaining values cannot
-contribute to the overall top-k and can therefore be pruned".
+result held in VMEM.  The paper's pruning rule survives, applied per row:
+a row whose distance is not below the current k-th best is never merged,
+so a tile whose minimum is not below it runs no merge round -- exactly
+"the remaining values cannot contribute to the overall top-k and can
+therefore be pruned".
 
 Grid is (Q, num_tiles): the LUT of query q stays resident in VMEM while its
 tiles stream -- one query's scan is the paper's "single cluster processed by
@@ -60,11 +61,37 @@ the unpruned scan.  The soundness argument, which the equivalence test wall
   the same candidates at the same positions, and the output is bit-identical
   -- distances *and* ids, ties included.
 
-The per-tile merge (`merge_topk`) selects the k smallest of the
-(k + block_n) candidate set in k rounds of vector min-reductions, each
-taking the smallest remaining (value, position) pair -- exactly the order
-of a stable ascending sort with the running entries first, which keeps
-every tie order of the earlier sort-based merge.
+* **Per-row admission**: the same argument, row by row, inside a scanned
+  tile.  A row is merged iff ``d < pair_kth`` (strict: a running entry
+  wins a tie, so the row could not enter) and ``d <= qbound`` with
+  ``qbound = min(b0, sq)`` (non-strict, so ties at the final k-th
+  survive).  A dropped row is strictly beyond the final k-th.  Every row
+  at or below it is admitted, and so is every row that precedes one in
+  stable (value, position) order, so each keeps its position in the
+  pair's list.  A pair's list is thus the k smallest of its *admitted*
+  rows: it agrees with the unfiltered list on every entry at or below the
+  current ``sq``, and past it may hold other entries or (+inf, -1) tails,
+  as a pair that emits no tiles already returns.  Its k-th is never below
+  the unfiltered list's, and differs only where that one already exceeds
+  ``sq``; then ``min(sq, k-th)`` is ``sq`` on both sides, so ``sq``'s
+  trajectory is unchanged, and so is every skip decision: where the k-ths
+  differ, ``qbound < unfiltered k-th <= filtered k-th``, so a tile with
+  ``lb > qbound`` skips on both sides and one with ``lb <= qbound`` lies
+  below both k-ths (``lb >= pair_kth`` is false on both).  The flat
+  kernel's ``qbound`` is the caller's strict bound; the pairs kernel's is
+  +inf, and admission reduces to the pair's k-th.
+
+The per-tile merge (`merge_admitted`) inserts the admitted rows one per
+round: the smallest admitted (value, lane) takes two tile reductions, its
+id is ``row0 + lane`` (tile ids are affine in the lane), and it lands after
+every running entry ``<=`` its value -- running entries and earlier
+insertions first, the order of a stable ascending sort -- while the tail
+shifts one lane right and the k-th drops off.  Rounds stop once the
+smallest admitted value is not below the row's current k-th, so a pair
+pays about k·ln(N/k) insertions over its N rows in scan order, where a
+re-selection of the whole row would pay k rounds on every merging tile.
+The insertions are the kernels' third stats lane (rows merged);
+`ref.merge_topk_rounds_ref` keeps the k-round re-selection as the oracle.
 
 TPU layout (what Mosaic accepts): tables arrive as (A / 128, 128) chunk
 blocks and code tiles are scanned transposed (`adc_scan.tile_dists`); the
@@ -101,84 +128,81 @@ def tile_queue_chunks(t_n: int) -> tuple[int, int]:
     return chunk, -(-t_n // chunk)
 
 
-def merge_topk(
+def merge_admitted(
     cur_v: jax.Array,
     cur_i: jax.Array,
     dists: jax.Array,
-    ids: jax.Array,
-    k: int,
-) -> tuple[jax.Array, jax.Array]:
-    """k smallest of [cur | tile] in stable (value, position) order.
+    row0: jax.Array,
+    kth: jax.Array,
+    qbound: jax.Array,
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """Insert the tile rows that can reach the answer into the running top-k.
 
-    cur_v / cur_i: (1, k) running top-k, ascending; dists / ids: (1, BN)
-    tile candidates.  Running entries take positions 0..k-1 and tile rows
-    k + lane, so each round's pick -- the smallest (value, position) pair
-    above the previous pick -- reproduces a stable ascending sort.
+    cur_v / cur_i: (1, k) running top-k, ascending, with `kth` its last
+    value; dists: (1, BN) tile distances (+inf past the valid rows), whose
+    row `lane` has id `row0 + lane`.  A row is admitted iff
+    `d < kth` (a running entry wins a tie) and `d <= qbound`.  Each round
+    takes the smallest admitted (value, lane) and places it after every
+    entry <= its value, so the row stays in stable (value, position)
+    order; rounds stop once the smallest admitted value is not below the
+    row's current k-th.  Returns (vals, idx, rows merged).
     """
-    pc = jax.lax.broadcasted_iota(jnp.int32, cur_v.shape, 1)
-    pt = jax.lax.broadcasted_iota(jnp.int32, dists.shape, 1) + k
+    lane = jax.lax.broadcasted_iota(jnp.int32, dists.shape, 1)
+    pos = jax.lax.broadcasted_iota(jnp.int32, cur_v.shape, 1)
+    cand = jnp.where((dists < kth) & (dists <= qbound), dists, jnp.inf)
 
-    def rmin(x):
-        return jnp.min(x, axis=1, keepdims=True)
+    def below_kth(carry):
+        _, _, _, m, kth, _ = carry
+        return m < kth
 
-    def pick(j, carry):
-        out_v, out_i, lv, lp = carry
-        ac = (cur_v > lv) | ((cur_v == lv) & (pc > lp))
-        at = (dists > lv) | ((dists == lv) & (pt > lp))
-        m = jnp.minimum(
-            rmin(jnp.where(ac, cur_v, jnp.inf)),
-            rmin(jnp.where(at, dists, jnp.inf)),
-        )
-        p = jnp.minimum(
-            rmin(jnp.where(ac & (cur_v == m), pc, BIG)),
-            rmin(jnp.where(at & (dists == m), pt, BIG)),
-        )
-        sel = jnp.minimum(
-            rmin(jnp.where(pc == p, cur_i, BIG)),
-            rmin(jnp.where(pt == p, ids, BIG)),
-        )
-        return (
-            jnp.where(pc == j, m, out_v), jnp.where(pc == j, sel, out_i),
-            m, p,
-        )
+    def insert(carry):
+        v, i, cand, m, _, n = carry
+        pick = jnp.min(jnp.where(cand == m, lane, BIG))
+        # entries <= m keep their lane, the rest move one lane right and
+        # the k-th drops off; what a roll wraps into lane 0 is overridden
+        keep = v <= m
+        sv = pltpu.roll(v, 1, 1)
+        si = pltpu.roll(i, 1, 1)
+        at = (pos == 0) | (sv <= m)
+        v = jnp.where(keep, v, jnp.where(at, m, sv))
+        i = jnp.where(keep, i, jnp.where(at, row0 + pick, si))
+        cand = jnp.where(lane == pick, jnp.inf, cand)
+        return v, i, cand, jnp.min(cand), jnp.max(v), n + 1
 
-    init = (
-        cur_v, cur_i,
-        jnp.full((1, 1), -jnp.inf, cur_v.dtype),
-        jnp.full((1, 1), -1, jnp.int32),
+    v, i, _, _, _, n = jax.lax.while_loop(
+        below_kth, insert,
+        (cur_v, cur_i, cand, jnp.min(cand), kth, jnp.int32(0)),
     )
-    out_v, out_i, _, _ = jax.lax.fori_loop(0, k, pick, init)
-    return out_v, out_i
+    return v, i, n
 
 
 def _scan_and_merge(
     tab_ref, codes_t, v_ref, i_ref, row0, rows, kth, qbound, *,
-    k: int, path: str, add_offsets: bool,
+    path: str, add_offsets: bool,
 ):
-    """Distances of one code tile, merged into the (1, k) running top-k.
+    """Distances of one code tile, merged into the (1, k) running top-k;
+    returns the rows merged.
 
-    Rows at or past `rows` are padding (+inf).  The merge is skipped when
-    nothing in the tile beats the current k-th (paper §4.4) or when the
-    tile lies wholly past the query bound."""
+    Rows at or past `rows` are padding (+inf).  Only rows below the
+    current k-th and at or below the query bound are merged (module
+    docstring)."""
     dists = tile_dists(tab_ref, codes_t, path=path, add_offsets=add_offsets)
     lane = jax.lax.broadcasted_iota(jnp.int32, dists.shape, 1)
     dists = jnp.where(lane < rows, dists, jnp.inf)
-    tile_min = jnp.min(dists)
-
-    @pl.when((tile_min < kth) & (tile_min <= qbound))
-    def _merge():
-        out_v, out_i = merge_topk(
-            v_ref[...], i_ref[...], dists, row0 + lane, k
-        )
-        v_ref[...] = out_v
-        i_ref[...] = out_i
+    v, i, n = merge_admitted(
+        v_ref[...], i_ref[...], dists, row0, kth, qbound
+    )
+    v_ref[...] = v
+    i_ref[...] = i
+    return n
 
 
-def _count_skip(s_ref, rows):
-    """Add one skipped tile holding `rows` valid rows to (1, 2) counters."""
+def _add_stats(s_ref, skipped, avoided, merged):
+    """Add to a pair's (1, 3) [tiles skipped, rows avoided, rows merged]
+    counters."""
     lane = jax.lax.broadcasted_iota(jnp.int32, s_ref.shape, 1)
     s_ref[...] = s_ref[...] + jnp.where(
-        lane == 0, (rows > 0).astype(jnp.int32), rows
+        lane == 0, skipped, jnp.where(lane == 1, avoided, merged)
     )
 
 
@@ -190,7 +214,6 @@ def _adc_topk_kernel(
     vals_out,    # (1, k) running top-k of query q
     idx_out,
     *,
-    k: int,
     block_n: int,
     path: str,
 ):
@@ -202,15 +225,13 @@ def _adc_topk_kernel(
         vals_out[...] = jnp.full(vals_out.shape, jnp.inf, vals_out.dtype)
         idx_out[...] = jnp.full(idx_out.shape, -1, jnp.int32)
 
-    # §4.4 early pruning: skip the merge when nothing in this tile can beat
-    # the current k-th best, warm-started by the caller's per-query bound
-    # (a strict upper bound on the final k-th, so dropped rows can never
-    # appear in the output).
+    # §4.4 early pruning: merge only the rows that beat the current k-th
+    # best and the caller's per-query bound (a strict upper bound on the
+    # final k-th, so dropped rows can never appear in the output).
     rows = jnp.clip(nvalid_ref[0] - t * block_n, 0, block_n)
     _scan_and_merge(
         table_ref, addr_ref[...], vals_out, idx_out, t * block_n, rows,
-        jnp.max(vals_out[...]), bound_ref[q], k=k, path=path,
-        add_offsets=False,
+        jnp.max(vals_out[...]), bound_ref[q], path=path, add_offsets=False,
     )
 
 
@@ -221,7 +242,6 @@ def _adc_topk_pairs_kernel(
     vals_out,
     idx_out,
     *,
-    k: int,
     block_n: int,
     path: str,
 ):
@@ -237,7 +257,7 @@ def _adc_topk_pairs_kernel(
     rows = jnp.clip(nvalid_ref[p] - t * block_n, 0, block_n)
     _scan_and_merge(
         table_ref, addr_ref[...], vals_out, idx_out, t * block_n, rows,
-        jnp.max(vals_out[...]), jnp.inf, k=k, path=path, add_offsets=False,
+        jnp.max(vals_out[...]), jnp.inf, path=path, add_offsets=False,
     )
 
 
@@ -257,10 +277,9 @@ def _adc_topk_tiles_kernel(
     sq_in,       # (Q,) f32 SMEM running per-query bounds
     vals_out,    # (1, k) running top-k of this tile's pair
     idx_out,
-    stats_out,   # (1, 2) int32 [tiles skipped, rows avoided] of this pair
+    stats_out,   # (1, 3) int32 [tiles skipped, rows avoided, rows merged]
     sq_out,      # (Q,) f32 SMEM running per-query bounds
     *,
-    k: int,
     path: str,
     add_offsets: bool,
 ):
@@ -319,14 +338,14 @@ def _adc_topk_tiles_kernel(
 
     @pl.when(skip)
     def _account():
-        _count_skip(stats_out, rows)
+        _add_stats(stats_out, (rows > 0).astype(jnp.int32), rows, 0)
 
     @pl.when(jnp.logical_not(skip))
     def _scan():
-        _scan_and_merge(
+        _add_stats(stats_out, 0, 0, _scan_and_merge(
             table_ref, codes_ref[...], vals_out, idx_out, tr_ref[t], rows,
-            kth, qbound, k=k, path=path, add_offsets=add_offsets,
-        )
+            kth, qbound, path=path, add_offsets=add_offsets,
+        ))
 
     # tighten the running query bound with this pair's (post-merge) k-th
     sq_out[qi] = jnp.minimum(sq_out[qi], jnp.max(vals_out[...]))
@@ -362,19 +381,19 @@ def _tiles_chunk_call(
             pl.BlockSpec((w, block_n), imap_codes),
             pl.BlockSpec((None, 1, k), imap_first),
             pl.BlockSpec((None, 1, k), imap_first),
-            pl.BlockSpec((None, 1, 2), imap_first),
+            pl.BlockSpec((None, 1, 3), imap_first),
             smem,
         ],
         out_specs=[
             pl.BlockSpec((None, 1, k), imap_out),
             pl.BlockSpec((None, 1, k), imap_out),
-            pl.BlockSpec((None, 1, 2), imap_out),
+            pl.BlockSpec((None, 1, 3), imap_out),
             smem,
         ],
     )
     return pl.pallas_call(
         functools.partial(
-            _adc_topk_tiles_kernel, k=k, path=path, add_offsets=add_offsets
+            _adc_topk_tiles_kernel, path=path, add_offsets=add_offsets
         ),
         grid_spec=grid_spec,
         out_shape=[
@@ -422,7 +441,9 @@ def adc_topk_tiles_kernel(
 
     `pair_lb` / `bound` enable whole-tile pruning (module docstring); the
     defaults (-inf / +inf) reproduce the unpruned scan bit-for-bit.  Returns
-    ((P, k) dists, (P, k) idx, (P, 2) int32 [tiles skipped, rows avoided]).
+    ((P, k) dists, (P, k) idx, (P, 3) int32 [tiles skipped, rows avoided,
+    rows merged]).  Rows merged counts the insertions into the pair's
+    running top-k.
     """
     p = tables.shape[0]
     t_n = tile_pair.shape[0]
@@ -462,7 +483,7 @@ def adc_topk_tiles_kernel(
     state = (
         jnp.full((p + 1, 1, k), jnp.inf, tables.dtype),
         jnp.full((p + 1, 1, k), -1, jnp.int32),
-        jnp.zeros((p + 1, 1, 2), jnp.int32),
+        jnp.zeros((p + 1, 1, 3), jnp.int32),
         bound.astype(jnp.float32),
     )
     chunks = as_chunks(tables)
@@ -494,10 +515,9 @@ def _adc_topk_windows_kernel(
     codes_ref,       # (W, block_n) tile selected by the prefetched index map
     vals_out,        # (1, k) running top-k of pair p
     idx_out,
-    stats_out,       # (1, 2) int32 [tiles skipped, rows avoided] of pair p
+    stats_out,       # (1, 3) int32 [skipped, avoided, merged] of pair p
     sq,              # (Q,) f32 SMEM running per-query bound
     *,
-    k: int,
     block_n: int,
     path: str,
     add_offsets: bool = False,
@@ -534,14 +554,14 @@ def _adc_topk_windows_kernel(
 
     @pl.when(skip)
     def _account():
-        _count_skip(stats_out, rows)
+        _add_stats(stats_out, (rows > 0).astype(jnp.int32), rows, 0)
 
     @pl.when(jnp.logical_not(skip))
     def _scan():
-        _scan_and_merge(
+        _add_stats(stats_out, 0, 0, _scan_and_merge(
             table_ref, codes_ref[...], vals_out, idx_out, t * block_n, rows,
-            kth, qbound, k=k, path=path, add_offsets=add_offsets,
-        )
+            kth, qbound, path=path, add_offsets=add_offsets,
+        ))
 
     sq[qi] = jnp.minimum(sq[qi], jnp.max(vals_out[...]))
 
@@ -585,7 +605,7 @@ def adc_topk_windows_kernel(
 
     Returns:
       ((P, k) ascending distances, (P, k) int32 window-row indices,
-       (P, 2) int32 [tiles skipped, rows avoided]).
+       (P, 3) int32 [tiles skipped, rows avoided, rows merged]).
     """
     p = tables.shape[0]
     assert window % block_n == 0
@@ -626,20 +646,20 @@ def adc_topk_windows_kernel(
         out_specs=[
             pl.BlockSpec((None, 1, k), lambda pi, ti, *_: (pi, 0, 0)),
             pl.BlockSpec((None, 1, k), lambda pi, ti, *_: (pi, 0, 0)),
-            pl.BlockSpec((None, 1, 2), lambda pi, ti, *_: (pi, 0, 0)),
+            pl.BlockSpec((None, 1, 3), lambda pi, ti, *_: (pi, 0, 0)),
         ],
         scratch_shapes=[pltpu.SMEM((n_queries,), jnp.float32)],
     )
     vals, idx, stats = pl.pallas_call(
         functools.partial(
-            _adc_topk_windows_kernel, k=k, block_n=block_n, path=path,
+            _adc_topk_windows_kernel, block_n=block_n, path=path,
             add_offsets=add_offsets,
         ),
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((p, 1, k), tables.dtype),
             jax.ShapeDtypeStruct((p, 1, k), jnp.int32),
-            jax.ShapeDtypeStruct((p, 1, 2), jnp.int32),
+            jax.ShapeDtypeStruct((p, 1, 3), jnp.int32),
         ],
         interpret=interpret,
     )(
@@ -696,7 +716,7 @@ def adc_topk_pairs_kernel(
     )
     vals, idx = pl.pallas_call(
         functools.partial(
-            _adc_topk_pairs_kernel, k=k, block_n=block_n, path=path
+            _adc_topk_pairs_kernel, block_n=block_n, path=path
         ),
         grid_spec=grid_spec,
         out_shape=[
@@ -757,7 +777,7 @@ def adc_topk_kernel(
     )
     vals, idx = pl.pallas_call(
         functools.partial(
-            _adc_topk_kernel, k=k, block_n=block_n, path=path
+            _adc_topk_kernel, block_n=block_n, path=path
         ),
         grid_spec=grid_spec,
         out_shape=[

@@ -234,8 +234,8 @@ def adc_topk_windows(
 
     `pair_q`/`pair_lb`/`bound` drive the early-pruning-v2 whole-tile skip
     (see adc_topk.py); the defaults reproduce the unpruned scan exactly.
-    With `with_stats=True` additionally returns the (P, 2) int32
-    [tiles skipped, rows avoided] counters.
+    With `with_stats=True` additionally returns the (P, 3) int32
+    [tiles skipped, rows avoided, rows merged] counters.
     """
     interpret = interpret_mode(interpret)
     start_blocks = starts.astype(jnp.int32) // block_n
@@ -296,8 +296,8 @@ def adc_topk_tiles(
 
     `pair_q`/`pair_lb`/`bound` drive the early-pruning-v2 whole-tile skip
     (see adc_topk.py); the defaults reproduce the unpruned scan exactly.
-    With `with_stats=True` additionally returns the (P, 2) int32
-    [tiles skipped, rows avoided] counters.
+    With `with_stats=True` additionally returns the (P, 3) int32
+    [tiles skipped, rows avoided, rows merged] counters.
     """
     interpret = interpret_mode(interpret)
     vals, idx, stats = _topk.adc_topk_tiles_kernel(

@@ -80,3 +80,55 @@ def ext_lut_build_ref(
     sums = jnp.sum(lut[:, combo_cols, combo_codes], axis=-1)  # (Q, m)
     zero = jnp.zeros((q, 1), lut.dtype)
     return jnp.concatenate([lut.reshape(q, -1), sums, zero], axis=-1)
+
+
+def merge_topk_rounds_ref(
+    cur_v: jax.Array,
+    cur_i: jax.Array,
+    dists: jax.Array,
+    ids: jax.Array,
+    k: int,
+) -> tuple[jax.Array, jax.Array]:
+    """k smallest of [cur | tile] in stable (value, position) order, by k
+    rounds of re-selection (the oracle of `adc_topk.merge_admitted`).
+
+    cur_v / cur_i: (1, k) running top-k, ascending; dists / ids: (1, BN)
+    tile candidates.  Running entries take positions 0..k-1 and tile rows
+    k + lane, so each round's pick -- the smallest (value, position) pair
+    above the previous pick -- reproduces a stable ascending sort.
+    """
+    big = jnp.iinfo(jnp.int32).max
+    pc = jax.lax.broadcasted_iota(jnp.int32, cur_v.shape, 1)
+    pt = jax.lax.broadcasted_iota(jnp.int32, dists.shape, 1) + k
+
+    def rmin(x):
+        return jnp.min(x, axis=1, keepdims=True)
+
+    def pick(j, carry):
+        out_v, out_i, lv, lp = carry
+        ac = (cur_v > lv) | ((cur_v == lv) & (pc > lp))
+        at = (dists > lv) | ((dists == lv) & (pt > lp))
+        m = jnp.minimum(
+            rmin(jnp.where(ac, cur_v, jnp.inf)),
+            rmin(jnp.where(at, dists, jnp.inf)),
+        )
+        p = jnp.minimum(
+            rmin(jnp.where(ac & (cur_v == m), pc, big)),
+            rmin(jnp.where(at & (dists == m), pt, big)),
+        )
+        sel = jnp.minimum(
+            rmin(jnp.where(pc == p, cur_i, big)),
+            rmin(jnp.where(pt == p, ids, big)),
+        )
+        return (
+            jnp.where(pc == j, m, out_v), jnp.where(pc == j, sel, out_i),
+            m, p,
+        )
+
+    init = (
+        cur_v, cur_i,
+        jnp.full((1, 1), -jnp.inf, cur_v.dtype),
+        jnp.full((1, 1), -1, jnp.int32),
+    )
+    out_v, out_i, _, _ = jax.lax.fori_loop(0, k, pick, init)
+    return out_v, out_i

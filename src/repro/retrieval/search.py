@@ -49,9 +49,10 @@ class InFlightSearch:
         circular import with engine.py).
       dev_rows: (ndev,) int64 rows the device scan visits for this plan —
         the load report consumed by the scheduler's `load_carry`.
-      prune_stats: (ndev, 2) int32 device array (in flight): per device,
-        [tiles whose body the bound check skipped, valid rows in them] —
-        the early-pruning telemetry consumed by `ServingStats`.
+      prune_stats: (ndev, 3) int32 device array (in flight): per device,
+        [tiles whose body the bound check skipped, valid rows in them,
+        rows merged into the running top-k lists] — the scan telemetry
+        consumed by `ServingStats`.
       query_bound: (Q,) f32 warm-start bounds this dispatch ran with
         (host copy, so telemetry never recomputes them).
     """
@@ -176,8 +177,8 @@ def _device_search(
             add_offsets=add_offsets, interpret=interpret,
             pair_q=pair_q, pair_lb=pair_lb,
             bound=query_bound, n_queries=n_queries, with_stats=True,
-        )  # (P, k) dists, (P, k) window-row idx, (P, 2) prune counters
-    prune_dev = prune.sum(axis=0).reshape(1, 2)  # (1, 2) device totals
+        )  # (P, k) dists, (P, k) window-row idx, (P, 3) scan counters
+    prune_dev = prune.sum(axis=0).reshape(1, 3)  # (1, 3) device totals
 
     tv = jnp.where(pair_valid[:, None], tv, jnp.inf)
     # device row of each candidate; slots past a pair's candidates (+inf)
@@ -362,7 +363,8 @@ def sharded_search(
     `pair_lb` ((ndev, P) f32) and `query_bound` ((Q,) f32, replicated)
     drive the early-pruning whole-tile skip; (-inf, +inf) sentinels run
     the scan unpruned with the same executable.  Returns
-    (out_d (Q, k), out_i (Q, k), prune_stats (ndev, 2) int32).
+    (out_d (Q, k), out_i (Q, k), prune_stats (ndev, 3) int32:
+    [tiles skipped, rows avoided, rows merged] per device).
     """
     spec_dev = jax.sharding.PartitionSpec(DPU_AXIS)
     spec_rep = jax.sharding.PartitionSpec()

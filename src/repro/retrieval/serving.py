@@ -154,8 +154,10 @@ class ServingStats:
         log-bucketed histograms, and read 0.0 with metrics off.  Some
         families live in the registry only, with no field here:
         `upanns_scan_steps_total` (the scan kernel's grid steps by what
-        they serve), which is also added to
-        `repro.obs.metrics.PROCESS_REGISTRY` unless metrics are off.
+        they serve) and `upanns_scan_rows_merged_total` (tile rows the
+        scan inserted into its running top-k lists, per device), both
+        also added to `repro.obs.metrics.PROCESS_REGISTRY` unless
+        metrics are off.
 
     Scan / early-pruning telemetry:
       rows_scanned: total code rows visited by collected batches.
@@ -267,12 +269,17 @@ class ServingStats:
                  "Scan kernel grid steps, by what they serve", ("kind",))
         self.m_scan_steps = r.counter(*steps)
         # its process-lifetime twin, which outlives this engine
-        self.m_scan_steps_process = (
-            NULL_REGISTRY if isinstance(r, NullRegistry)
-            else PROCESS_REGISTRY).counter(*steps)
+        process = (NULL_REGISTRY if isinstance(r, NullRegistry)
+                   else PROCESS_REGISTRY)
+        self.m_scan_steps_process = process.counter(*steps)
         for kind in SCAN_STEP_KINDS:  # eager: exposition order is stable
             self.m_scan_steps.labels(kind=kind)
             self.m_scan_steps_process.labels(kind=kind)
+        merged = ("upanns_scan_rows_merged_total",
+                  "Tile rows inserted into the scan's running top-k lists, "
+                  "per device", ("device",))
+        self.m_rows_merged = r.counter(*merged)
+        self.m_rows_merged_process = process.counter(*merged)
         self.m_tiles_skipped = r.counter(
             "upanns_tiles_skipped_total",
             "Tile bodies the pruning-bound check skipped whole, per device",
@@ -349,6 +356,13 @@ class ServingStats:
         for kind, n in zip(SCAN_STEP_KINDS, steps):
             self.m_scan_steps.inc(n, kind=kind)
             self.m_scan_steps_process.inc(n, kind=kind)
+
+    def note_rows_merged(self, per_device) -> None:
+        """One batch's rows merged by each device's scan, into the
+        engine's registry and the process-lifetime one."""
+        for dev, n in enumerate(per_device):
+            self.m_rows_merged.inc(float(n), device=dev)
+            self.m_rows_merged_process.inc(float(n), device=dev)
 
     def note_compile(self) -> None:
         self.compiles += 1
@@ -1400,6 +1414,7 @@ class ServingEngine:
                     st.m_tiles_skipped.inc(float(ps[dev, 0]), device=dev)
                 if ps[dev, 1]:
                     st.m_rows_pruned.inc(float(ps[dev, 1]), device=dev)
+            st.note_rows_merged(ps[:, 2])
             tot = ps.sum(axis=0)
             skipped, rows = int(tot[0]), int(tot[1])
         st.tiles_dispatched += tiles

@@ -11,6 +11,8 @@ micro-batch's padding rows) and `bucket` (capacity dummies).  Pinned here:
   * `upanns_scan_steps_total` adds exactly the collected batches' counts,
     in the engine's registry and in the process-lifetime one (nothing
     with metrics off, nothing at warm-up);
+  * `upanns_scan_rows_merged_total` adds exactly the kernels' rows-merged
+    lane of the collected batches, per device, in both registries;
   * `Tracer(profiler=True)` hands the `dispatch` span's step counts to
     its `jax.profiler.TraceAnnotation`;
   * the search and re-rank steps carry their named scopes.
@@ -37,6 +39,7 @@ NPROBE = 8
 K = 10
 MB = 16
 STEPS = "upanns_scan_steps_total"
+MERGED = "upanns_scan_rows_merged_total"
 
 
 @pytest.fixture(scope="module")
@@ -141,6 +144,46 @@ def _process_counter():
     return {k: fam.get(kind=k) for k in SCAN_STEP_KINDS}
 
 
+def _merged(fam) -> dict:
+    """{device: rows merged} of a rows-merged counter family."""
+    return {} if fam is None else {
+        key[0]: s.value for key, s in fam.series.items()}
+
+
+def _process_merged() -> dict:
+    return _merged(PROCESS_REGISTRY.families().get(MERGED))
+
+
+@pytest.mark.parametrize("scan", ["tiles", "windows"])
+def test_rows_merged_counter_is_the_kernel_lane(engine, clustered_data, scan):
+    """The rows-merged counter's delta, per device, equals the kernels'
+    third stats lane summed over the collected batches."""
+    qs = clustered_data[2]
+    eng = dataclasses.replace(engine, scan=scan)
+    srv = ServingEngine(eng, nprobe=NPROBE, k=K, micro_batch=MB,
+                        pipeline_depth=1)
+    srv.warmup()
+    lanes = []
+    orig = srv._collect_micro_batch
+
+    def spy(handle, q_n, *a, **kw):
+        lanes.append(np.asarray(handle.prune_stats)[:, 2])
+        return orig(handle, q_n, *a, **kw)
+
+    srv._collect_micro_batch = spy
+    before = _merged(srv.stats.registry.families()[MERGED])
+    p_before = _process_merged()
+    for n in (1, 16, 24, 7):
+        srv.search(qs[:n])
+    want = np.sum(lanes, axis=0)
+    assert len(lanes) == 5 and want.sum() > 0
+    after = _merged(srv.stats.registry.families()[MERGED])
+    p_after = _process_merged()
+    for dev, n in enumerate(want):
+        assert after[str(dev)] - before.get(str(dev), 0.0) == n
+        assert p_after[str(dev)] - p_before.get(str(dev), 0.0) == n
+
+
 @pytest.mark.parametrize("scan", ["tiles", "windows"])
 def test_counter_delta_is_the_collected_batches(engine, clustered_data, scan):
     """The counter's delta over a ragged stream equals the sum of the
@@ -177,15 +220,17 @@ def test_metrics_off_counts_no_process_steps(engine, clustered_data):
     """With metrics off neither registry counts, and warm-up (which
     collects no micro-batch) adds nothing to the process registry."""
     qs = clustered_data[2]
-    before = _process_counter()
+    before, merged = _process_counter(), _process_merged()
     on = ServingEngine(engine, nprobe=NPROBE, k=K, micro_batch=MB)
     on.warmup()
     assert _process_counter() == before
+    assert _process_merged() == merged
     off = ServingEngine(engine, nprobe=NPROBE, k=K, micro_batch=MB,
                         metrics=False)
     off.warmup()
     off.search(qs[:24])
     assert _process_counter() == before
+    assert _process_merged() == merged
     assert off.stats.snapshot() == {}
 
 

@@ -17,7 +17,7 @@ import pytest
 
 from repro.core.index import IVFPQIndex
 from repro.core.placement import place_clusters
-from repro.kernels import ops, ref
+from repro.kernels import adc_topk, ops, ref
 from repro.kernels.adc_topk import adc_topk_tiles_kernel, adc_topk_windows_kernel
 from repro.retrieval import MemANNSEngine, build_shards
 from repro.retrieval.engine import make_dpu_mesh
@@ -265,7 +265,7 @@ def test_all_dummy_tile_list_pruned_matches_unpruned():
         np.where(mask, np.inf, np.asarray(tvp)),
     )
     np.testing.assert_array_equal(
-        np.where(mask, 0, np.asarray(stats)), np.zeros((p, 2), np.int32)
+        np.where(mask, 0, np.asarray(stats)), np.zeros((p, 3), np.int32)
     )
 
 
@@ -290,9 +290,61 @@ def test_pruning_reports_skips_and_stays_exact_on_skew():
     plan_u = eng_ref.plan_batch(qs, 8)
     handle_u = eng_ref.dispatch_plan(plan_u, 10)
     d_u, i_u = eng_ref.collect(handle_u)
-    assert int(np.asarray(handle_u.prune_stats).sum()) == 0
+    # unpruned: nothing skipped, nothing avoided (lanes 0-1); rows still
+    # merge (lane 2)
+    assert int(np.asarray(handle_u.prune_stats)[:, :2].sum()) == 0
     np.testing.assert_array_equal(d_p, d_u)
     np.testing.assert_array_equal(i_p, i_u)
+
+
+def _rounds_merge(cur_v, cur_i, dists, row0, kth, qbound):
+    """The k-round re-selection of the whole row, behind the whole-tile
+    gate (tile min below the k-th and at or below the query bound)."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, dists.shape, 1)
+    v, i = ref.merge_topk_rounds_ref(
+        cur_v, cur_i, dists, row0 + lane, cur_v.shape[1]
+    )
+    t_min = jnp.min(dists)
+    go = (t_min < kth) & (t_min <= qbound)
+    return jnp.where(go, v, cur_v), jnp.where(go, i, cur_i), jnp.int32(0)
+
+
+@pytest.fixture
+def fresh_traces():
+    """Kernels traced inside the test are dropped on both sides of it."""
+    jax.clear_caches()
+    yield
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("kind", ["giant", "empties"])
+def test_admission_merge_keeps_the_rounds_merge_skips_and_results(
+    kind, monkeypatch, fresh_traces
+):
+    """On the skewed layouts the admission merge skips the same tiles and
+    avoids the same rows (stats lanes 0-1) as the k-round merge, and the
+    step's results are bit-identical."""
+    rng = np.random.default_rng(13)
+    eng = _engine_from_sizes(rng, SIZES[kind])
+    qs = rng.normal(0, 50, (10, 16)).astype(np.float32)
+    plan = eng.plan_batch(qs, 8)
+    assert plan.pruned
+
+    def run():
+        handle = eng.dispatch_plan(plan, 10)
+        d, i = eng.collect(handle)
+        return d, i, np.asarray(handle.prune_stats)
+
+    d_new, i_new, s_new = run()
+    monkeypatch.setattr(adc_topk, "merge_admitted", _rounds_merge)
+    jax.clear_caches()
+    d_old, i_old, s_old = run()
+    np.testing.assert_array_equal(s_new[:, :2], s_old[:, :2])
+    assert s_new[:, 2].sum() > 0 and s_old[:, 2].sum() == 0
+    np.testing.assert_array_equal(d_new, d_old)
+    np.testing.assert_array_equal(i_new, i_old)
+    if kind == "giant":
+        assert s_new[:, 0].sum() > 0
 
 
 def test_mutable_churn_pruned_bit_identical_at_zero_recompiles():
